@@ -35,6 +35,9 @@ def main() -> None:
     ap.add_argument("--only", default="", help="comma-separated subset")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import convergence, fault_recovery, obs_overhead
     from benchmarks import ptlm_bench, roofline_report, serve_load
     from benchmarks import shard_scaling, speedup, swap_overhead

@@ -47,7 +47,6 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist_lib
@@ -1051,7 +1050,7 @@ class Engine:
         cross-device traffic in the compiled program is the per-interval
         O(R) energy/rung/observable all-gathers (`make_sharded_interval_step`)
         — verifiable by `repro.hlo.collectives.parse_collectives` on the
-        lowered text.  ``check_rep=False``: replicated outputs (stats, phase,
+        lowered text.  ``check_vma=False``: replicated outputs (stats, phase,
         t) are *computed* redundantly from identical inputs, which the static
         replication checker cannot prove.
         """
@@ -1080,12 +1079,12 @@ class Engine:
         pt_specs = dist_lib.pt_partition_specs(state.pt, cfg.n_chains)
         stats_specs = dist_lib.replicated_partition_specs(state.stats, cfg.n_chains)
         trace_spec = P(CHAIN_AXIS) if cfg.n_chains > 1 else P()
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=self._mesh,
             in_specs=(pt_specs, stats_specs, P(None)),
             out_specs=(pt_specs, stats_specs, trace_spec),
-            check_rep=False,
+            check_vma=False,
         )
 
     def _compiled(self, state: EngineState, chunk_len: int):

@@ -21,11 +21,11 @@ decision.  The *same jnp ops* run in three places:
 That sharing is what makes interpret-mode bit-equality against the
 `repro.exchange` DEO/SEO strategy + `core.swap.accept_pairs` oracle (fed the
 same counter-stream uniforms) hold by construction, and it is why everything
-here is written Mosaic-friendly: 1-D `broadcasted_iota` instead of
-``arange``, one-hot broadcast-compare-sum instead of gather/argsort (an
-arbitrary slot→rung permutation has no static gather pattern Mosaic can
-lower; at O(R²) on R scalars the one-hot form is noise next to the O(R·L²)
-sweeps).
+here is written Mosaic-friendly: (R, 1, 1) columns and (1, 1, R) rows
+from `broadcasted_iota` instead of 1-D ``arange``, one-hot
+broadcast-compare-sum instead of gather/argsort (an arbitrary slot→rung
+permutation has no static gather pattern Mosaic can lower; at O(R²) on R
+scalars the one-hot form is noise next to the O(R·L²) sweeps).
 
 Scope: temp-mode DEO/SEO only.  State-mode swaps move O(R·L²) lattice bytes
 (exactly what fusion exists to avoid), `windowed` builds its random matching
@@ -41,54 +41,74 @@ from repro.core import swap as swap_lib
 from repro.kernels import prng
 
 __all__ = [
+    "to_row",
     "pair_partners",
     "onehot_gather",
     "rung_energies",
     "decide",
+    "exchange_columns",
     "exchange_step",
 ]
 
 PAIRINGS = ("deo", "seo")
 
 
-def _iota(n: int) -> jnp.ndarray:
-    # broadcasted_iota lowers on Mosaic where 1-D `arange`/`iota` does not
-    return jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
+# Kernel bodies keep every per-replica row as an (n, 1, 1) *column*: it
+# broadcasts against the (n, L, L) lattice block as it stands, where a 1-D
+# (n,) row would need a shape cast Mosaic cannot lower.  A one-hot gather
+# compares a column against a (1, 1, n) *row* of the same values; both live
+# in the three minor-most dims Mosaic tiles, so nothing is ever reshaped.
+
+
+def _col(n: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, (n, 1, 1), 0)
+
+
+def _row(n: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, (1, 1, n), 2)
+
+
+def to_row(col: jnp.ndarray) -> jnp.ndarray:
+    """(n, 1, 1) column -> (1, 1, n) row of the same values (one-hot sum)."""
+    n = col.shape[0]
+    eq = _col(n) == _row(n)
+    return jnp.sum(jnp.where(eq, col, jnp.zeros((), col.dtype)), axis=0,
+                   keepdims=True)
 
 
 def pair_partners(n: int, phase) -> jnp.ndarray:
-    """Mosaic-safe mirror of `core.swap.pair_partners` (same values)."""
-    idx = _iota(n)
+    """(n, 1, 1) mirror of `core.swap.pair_partners` (same values)."""
+    idx = _col(n)
     ph = jnp.asarray(phase, jnp.int32) % 2
     even = idx ^ 1
     odd = jnp.where(idx == 0, 0, ((idx - 1) ^ 1) + 1)
     partner = jnp.where(ph == 0, even, odd)
-    return jnp.where(partner >= n, idx, partner).astype(jnp.int32)
+    return jnp.where(partner >= n, idx, partner)
 
 
 def onehot_gather(values: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """``values[idx]`` as a one-hot broadcast-compare-sum (Mosaic-safe).
+    """``values[idx]`` on (n, 1, 1) columns as a one-hot compare-sum.
 
     Exactly one term of each sum is nonzero, so the result is bitwise the
     gathered value (a ``-0.0`` entry surfaces as ``+0.0`` — value-equal,
     and impossible for the betas/energies this module gathers).
     """
     n = values.shape[0]
-    eq = idx[:, None] == _iota(n)[None, :]
     zero = jnp.zeros((), values.dtype)
-    return jnp.sum(jnp.where(eq, values[None, :], zero), axis=1)
+    return jnp.sum(jnp.where(idx == _row(n), to_row(values), zero), axis=2,
+                   keepdims=True)
 
 
 def rung_energies(rung: jnp.ndarray, energy: jnp.ndarray) -> jnp.ndarray:
-    """(R,) energy row in *rung* order from per-slot energies.
+    """(n, 1, 1) energy column in *rung* order from per-slot energies.
 
     The inversion-free form of ``energy[argsort(rung)]``: ``e_rung[r] =
     Σ_i energy[i]·[rung[i] == r]`` — argsort does not lower in a kernel
     body, the one-hot sum does.
     """
     n = rung.shape[0]
-    eq = _iota(n)[:, None] == rung[None, :]
-    return jnp.sum(jnp.where(eq, energy[None, :], 0.0), axis=1)
+    eq = _col(n) == to_row(rung)
+    return jnp.sum(jnp.where(eq, to_row(energy), 0.0), axis=2, keepdims=True)
 
 
 def decide(partner, betas, e_rung, u, criterion):
@@ -97,11 +117,11 @@ def decide(partner, betas, e_rung, u, criterion):
     Same decision structure (one uniform per rung, decided at the lower
     member, broadcast to both) and the shared `swap_probability`, so the
     outputs are bit-equal to ``accept_pairs(..., uniforms=u)`` — the oracle
-    the round kernels are pinned against.  Returns ``(perm, accept_at_lower,
-    prob_at_lower, attempt_at_lower)`` in `accept_pairs`' conventions.
+    the round kernels are pinned against.  All arguments and results are
+    (n, 1, 1) columns.  Returns ``(perm, accept_at_lower, prob_at_lower,
+    attempt_at_lower)`` in `accept_pairs`' conventions.
     """
-    n = partner.shape[0]
-    idx = _iota(n)
+    idx = _col(partner.shape[0])
     lower = jnp.minimum(idx, partner)
     is_lower = (partner != idx) & (idx == lower)
     p = swap_lib.swap_probability(
@@ -115,6 +135,29 @@ def decide(partner, betas, e_rung, u, criterion):
     perm = jnp.where(pair_accept, partner, idx)
     prob_at_lower = jnp.where(is_lower, p, 0.0)
     return perm, accept_at_lower, prob_at_lower, is_lower
+
+
+def exchange_columns(rung, energy, betas, phase, key_words, *, pairing,
+                     criterion):
+    """`exchange_step` on (n, 1, 1) columns — the form a kernel body calls.
+
+    ``key_words`` may be a (2,) array or a pair of scalars read from SMEM.
+    """
+    if pairing not in PAIRINGS:
+        raise ValueError(
+            f"in-kernel exchange supports pairings {PAIRINGS}, got {pairing!r}"
+        )
+    n = rung.shape[0]
+    e_rung = rung_energies(rung, energy)
+    u = prng.swap_uniforms(key_words, phase, n, shape=(n, 1, 1))
+    if pairing == "deo":
+        partner = pair_partners(n, phase)
+    else:
+        partner = pair_partners(n, prng.seo_coin(key_words, phase))
+    perm, accept, prob, attempt = decide(partner, betas, e_rung, u, criterion)
+    # temp mode: slot i holding rung r now holds perm[r]; states stay put.
+    new_rung = onehot_gather(perm, rung)
+    return new_rung, accept, prob, attempt, e_rung
 
 
 def exchange_step(
@@ -142,20 +185,10 @@ def exchange_step(
 
     Returns ``(new_rung, accept, prob, attempt, e_rung)``: the post-swap
     slot→rung map plus `accept_pairs`-convention lower-rung diagnostics and
-    the pre-swap rung-ordered energy row.
+    the pre-swap rung-ordered energy row, all (R,).
     """
-    if pairing not in PAIRINGS:
-        raise ValueError(
-            f"in-kernel exchange supports pairings {PAIRINGS}, got {pairing!r}"
-        )
-    n = rung.shape[0]
-    e_rung = rung_energies(rung, energy)
-    u = prng.swap_uniforms(key_words, phase, n)
-    if pairing == "deo":
-        partner = pair_partners(n, phase)
-    else:
-        partner = pair_partners(n, prng.seo_coin(key_words, phase))
-    perm, accept, prob, attempt = decide(partner, betas, e_rung, u, criterion)
-    # temp mode: slot i holding rung r now holds perm[r]; states stay put.
-    new_rung = onehot_gather(perm, rung)
-    return new_rung, accept, prob, attempt, e_rung
+    cols = [jnp.reshape(x, (-1, 1, 1)) for x in (rung, energy, betas)]
+    out = exchange_columns(
+        *cols, phase, key_words, pairing=pairing, criterion=criterion
+    )
+    return tuple(x.reshape(-1) for x in out)

@@ -3,10 +3,12 @@
 This is the paper's compute hot-spot (the per-iteration MH update, §3)
 re-thought for the TPU memory hierarchy (DESIGN.md §2/§6):
 
-* one grid step processes a **block of replicas** with their full (L, L)
-  lattices resident in VMEM — the analogue of the paper's "replicas per CUDA
+* one grid step moves a **block of replicas** with their full (L, L)
+  lattices into VMEM — the analogue of the paper's "replicas per CUDA
   block" question (Fig. 6); the block size `r_blk` is the tuning knob swept by
-  ``benchmarks/tile_sweep.py``;
+  ``benchmarks/tile_sweep.py``.  Inside the block the kernel sweeps one
+  replica at a time (`repro.kernels.lattice`, which holds the launch
+  plumbing all lattice kernels share);
 * both colour half-sweeps run back-to-back in-kernel, so each sweep costs one
   HBM round-trip of the spin block instead of two;
 * spins are int8 in HBM (8× denser than the f32 math dtype) and are widened
@@ -16,7 +18,8 @@ Three kernels share that tile strategy (DESIGN.md §6):
 
 * ``ising_sweep_pallas`` — **one sweep per launch**; the random uniforms are
   a kernel *input* stream ``(R, 2, L, L)`` f32, so the CPU
-  ``interpret=True`` path is bit-exact with `ref.ising_sweep`.  Modeled HBM
+  ``interpret=True`` path matches `ref.ising_sweep` exactly in spins and
+  acceptance counts.  Modeled HBM
   traffic: int8 spins in+out (2 B/cell) plus the externally generated
   uniforms stream (8 B/cell written by the generator + 8 B/cell read back) =
   **18 B/cell/sweep** (`hbm_bytes_per_cell_sweep`).
@@ -28,14 +31,19 @@ Three kernels share that tile strategy (DESIGN.md §6):
   *interval*, cutting modeled traffic to **2 B/cell/interval** plus O(R)
   scalars — the paper's single-launch device residency (its 986× CUDA
   recipe) applied to the TPU memory hierarchy.  The stream is deterministic
-  pure-uint32 arithmetic, so interpret mode is bit-exact with repeated
-  `ref.ising_sweep` application fed `prng.ising_sweep_uniforms`.
+  pure-uint32 arithmetic, so spins and acceptance counts are bit-exact
+  with repeated `ref.ising_sweep` application fed
+  `prng.ising_sweep_uniforms`.
 * ``ising_round_fused_pallas`` — **one launch = whole PT round(s)**: sweeps
   *plus* the temp-mode DEO/SEO exchange, with the swap uniforms drawn from
   the counter PRNG's swap stream (`prng.swap_uniforms`) and the slot↔rung
   permutation applied in-kernel (`repro.kernels.exchange`).  Eliminates the
   per-swap kernel exit + host round-trip entirely; with ``n_rounds > 1``
   the spin block stays VMEM-resident across multiple exchanges.
+
+ΔE totals are f32 sums over the lattice.  The order in which a backend
+reduces them is its own, so they agree with the oracle to rounding, not
+bit for bit; spins and acceptance counts are exact.
 
 All fused variants take ``pack_bits``: bit-plane **multispin packing** of
 the replica axis (Weigel, arXiv:1004.0023) — spins live as 1 bit per
@@ -44,7 +52,8 @@ tree, and ΔE is table-selected per replica; bitwise-identical trajectories
 to the unpacked path (pinned by tests).
 
 VMEM working set per grid step (bytes; pinned by tests/test_kernels.py and
-checked by the tile sweep):
+checked by the tile sweep).  The models count the whole block's working
+copies, an upper bound now that the kernels sweep one replica at a time:
 
 * per-sweep: r_blk · L² · (2 int8 in/out + 2·4 u-f32 + 4 f32 widened +
   4 f32 neighbour-sum) = 18·r_blk·L²; L=300, r_blk=8 ≈ 12.4 MiB — just
@@ -55,7 +64,7 @@ checked by the tile sweep):
   traffic, not VMEM footprint.
 
 On hardware, the trailing lattice dim should be padded to a multiple of 128
-lanes for full VPU utilization (the wrapper in ops.py reports alignment).
+lanes for full VPU utilization; L=300 runs on padded (8, 128) tiles.
 """
 from __future__ import annotations
 
@@ -63,59 +72,60 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels import exchange as _kx
-from repro.kernels import prng
-
-
-def _roll1(x: jnp.ndarray, shift: int, axis: int) -> jnp.ndarray:
-    """±1 circular shift via slice+concat (lowers on both Mosaic and CPU)."""
-    n = x.shape[axis]
-    if shift == 1:
-        a = jax.lax.slice_in_dim(x, n - 1, n, axis=axis)
-        b = jax.lax.slice_in_dim(x, 0, n - 1, axis=axis)
-    else:  # shift == -1
-        a = jax.lax.slice_in_dim(x, 1, n, axis=axis)
-        b = jax.lax.slice_in_dim(x, 0, 1, axis=axis)
-    return jnp.concatenate([a, b], axis=axis)
+from repro.kernels.lattice import (
+    Kind,
+    accept_prob,
+    fused_call,
+    roll1,
+    round_call,
+    site_sum,
+    sweep_call,
+)
 
 
-def _accept_prob(de, beta, rule):
-    """Mirror of `ref.accept_prob` (kept local: kernel code is self-contained)."""
-    if rule == "metropolis":
-        return jnp.exp(-beta * de)
-    if rule == "glauber":
-        return jax.nn.sigmoid(-beta * de)
-    raise ValueError(rule)
+def _widen(s: jnp.ndarray) -> jnp.ndarray:
+    """int8 spins -> f32, through int32 (Mosaic casts int8 only to int32)."""
+    return s.astype(jnp.int32).astype(jnp.float32)
 
 
-def _ising_sweep_kernel(
-    spins_ref, u_ref, beta_ref, out_ref, de_ref, nacc_ref, *, j, b, rule
-):
-    """One full checkerboard sweep over an (r_blk, L, L) block."""
-    s = spins_ref[...].astype(jnp.float32)  # widen in VMEM only
-    l = s.shape[-1]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    parity = (ii + jj) % 2
-    beta = beta_ref[...].astype(jnp.float32)[:, None, None]
+def _narrow(s: jnp.ndarray) -> jnp.ndarray:
+    return s.astype(jnp.int32).astype(jnp.int8)
 
-    de_total = jnp.zeros(s.shape[0], jnp.float32)
-    n_acc = jnp.zeros(s.shape[0], jnp.int32)
+
+def _ising_sweep_body(s, beta, parity, draw, *, j, b, rule):
+    """One checkerboard sweep (two half-sweeps) on a widened f32 spin group.
+
+    Shared by all three kernels: ``draw(colour)`` reads the uniforms from
+    the input stream or from the counter PRNG.  ``beta`` is a (g, 1, 1)
+    column.  Returns ``(s', delta_e (g, 1, 1), n_accepted (g, 1, 1))``.
+    """
+    ds = jnp.zeros(beta.shape, jnp.float32)
+    na = jnp.zeros(beta.shape, jnp.int32)
     for color in (0, 1):  # static unroll: two half-sweeps, one HBM round-trip
-        nbr = (
-            _roll1(s, 1, 1) + _roll1(s, -1, 1) + _roll1(s, 1, 2) + _roll1(s, -1, 2)
-        )
+        u = draw(color)
+        nbr = roll1(s, 1, 1) + roll1(s, -1, 1) + roll1(s, 1, 2) + roll1(s, -1, 2)
         de = 2.0 * s * (j * nbr - b)
-        accept = (u_ref[:, color] < _accept_prob(de, beta, rule)) & (parity == color)
+        accept = (u < accept_prob(de, beta, rule)) & (parity == color)
         s = jnp.where(accept, -s, s)
-        de_total = de_total + jnp.sum(jnp.where(accept, de, 0.0), axis=(1, 2))
-        n_acc = n_acc + jnp.sum(accept.astype(jnp.int32), axis=(1, 2))
+        ds = ds + site_sum(jnp.where(accept, de, 0.0))
+        na = na + site_sum(accept.astype(jnp.int32))
+    return s, ds, na
 
-    out_ref[...] = s.astype(jnp.int8)
-    de_ref[...] = de_total
-    nacc_ref[...] = n_acc
+
+def _kind(j, b, rule, pack_bits=False) -> Kind:
+    if pack_bits:
+        return Kind(
+            load=lambda s8: _pack_spins(_widen(s8)),
+            step=functools.partial(_ising_sweep_body_packed, j=j, b=b, rule=rule),
+            store=lambda words, n: _narrow(_unpack_spins(words, n)),
+            whole_block=True,
+        )
+    return Kind(
+        load=_widen,
+        step=functools.partial(_ising_sweep_body, j=j, b=b, rule=rule),
+        store=lambda s, _n: _narrow(s),
+    )
 
 
 def ising_sweep_pallas(
@@ -135,65 +145,11 @@ def ising_sweep_pallas(
       spins: (R, L, L) int8; R must be a multiple of ``r_blk`` (ops.py pads).
       u: (R, 2, L, L) f32 uniforms; betas: (R,) f32.
       r_blk: replicas per grid step (the Fig.-6 "block size" analogue).
-      interpret: True on CPU (bit-exact vs the oracle); False on real TPU.
+      interpret: True on CPU; False on real TPU.
     """
-    r, l, _ = spins.shape
-    assert r % r_blk == 0, (r, r_blk)
-    grid = (r // r_blk,)
-    kernel = functools.partial(_ising_sweep_kernel, j=j, b=b, rule=rule)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r_blk, l, l), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk, 2, l, l), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r_blk, l, l), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, l, l), jnp.int8),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(spins, u, betas)
-
-
-def _parity(l: int) -> jnp.ndarray:
-    """(l, l) checkerboard colour map from 2-D iotas (Mosaic-safe)."""
-    ii = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    return (ii + jj) % 2
-
-
-def _ising_sweep_body(s, beta, parity, w0, w1, *, j, b, rule):
-    """One checkerboard sweep (two half-sweeps) on a widened f32 spin block.
-
-    Shared by the interval-fused and whole-round kernels; the op sequence is
-    byte-for-byte the per-sweep kernel's, which is what keeps every fused
-    variant bit-exact against repeated `ref.ising_sweep` application.
-    Returns ``(s', delta_e (r,), n_accepted (r,))``.
-    """
-    l = parity.shape[-1]
-    beta3 = beta[:, None, None]
-    ds = jnp.zeros(s.shape[0], jnp.float32)
-    na = jnp.zeros(s.shape[0], jnp.int32)
-    for color in (0, 1):  # static unroll, exactly as the per-sweep kernel
-        u = prng.plane_uniforms(w0, w1, color, l, l)
-        nbr = (
-            _roll1(s, 1, 1) + _roll1(s, -1, 1)
-            + _roll1(s, 1, 2) + _roll1(s, -1, 2)
-        )
-        de = 2.0 * s * (j * nbr - b)
-        accept = (u < _accept_prob(de, beta3, rule)) & (parity == color)
-        s = jnp.where(accept, -s, s)
-        ds = ds + jnp.sum(jnp.where(accept, de, 0.0), axis=(1, 2))
-        na = na + jnp.sum(accept.astype(jnp.int32), axis=(1, 2))
-    return s, ds, na
+    return sweep_call(
+        _kind(j, b, rule), spins, u, betas, r_blk=r_blk, interpret=interpret
+    )
 
 
 # -- bit-plane multispin packing (Weigel, arXiv:1004.0023 §multi-spin) ---------
@@ -208,8 +164,9 @@ def _ising_sweep_body(s, beta, parity, w0, w1, *, j, b, rule):
 # nested `where`s on the count's 3 bit-planes.  The table entries are built
 # with the *same f32 op sequence* as the unpacked ``2.0 * s * (j*nbr - b)``
 # and the accept/ΔE planes are restacked to (r, l, l) before the *same* sum
-# reductions, so the packed path is bit-equal to the unpacked one — pinned
-# by tests/test_kernels.py.
+# reductions, so the packed path's spins and counts are bit-equal to the
+# unpacked one's, and its ΔE equal to rounding (the packed kernel reduces
+# the whole block at once) — pinned by tests/test_fused_round.py.
 
 
 def _pack_spins(s: jnp.ndarray):
@@ -230,6 +187,7 @@ def _unpack_spins(words, r: int) -> jnp.ndarray:
     planes = []
     for i in range(r):
         bit = (words[i // 32] >> jnp.uint32(i % 32)) & jnp.uint32(1)
+        bit = jax.lax.bitcast_convert_type(bit, jnp.int32)
         planes.append(2.0 * bit.astype(jnp.float32) - 1.0)
     return jnp.stack(planes)
 
@@ -266,31 +224,31 @@ def _ising_de_tables(j, b):
     return rows[-1.0], rows[1.0]
 
 
-def _ising_sweep_body_packed(words, beta, parity, w0, w1, *, j, b, rule):
-    """`_ising_sweep_body` on bit-plane-packed spins (same pytree protocol).
+def _ising_sweep_body_packed(words, beta, parity, draw, *, j, b, rule):
+    """`_ising_sweep_body` on bit-plane-packed spins (same protocol).
 
-    ``words`` is the `_pack_spins` tuple; r is recovered from the per-replica
-    sweep-key shape.  The uniforms draw, acceptance comparison, and ΔE /
-    acceptance reductions reuse the exact unpacked expressions on restacked
-    (r, l, l) planes — only the spin storage and neighbour count differ.
+    ``words`` is the `_pack_spins` tuple; r is recovered from the beta
+    column.  The uniforms draw, acceptance comparison, and ΔE / acceptance
+    reductions reuse the exact unpacked expressions on restacked (r, l, l)
+    planes — only the spin storage and neighbour count differ.
     """
-    r = w0.shape[0]
+    r = beta.shape[0]
     neg_tab, pos_tab = _ising_de_tables(j, b)
     one = jnp.uint32(1)
-    ds = jnp.zeros(r, jnp.float32)
-    na = jnp.zeros(r, jnp.int32)
+    ds = jnp.zeros(beta.shape, jnp.float32)
+    na = jnp.zeros(beta.shape, jnp.int32)
     for color in (0, 1):
-        u = prng.plane_uniforms(w0, w1, color, parity.shape[-1], parity.shape[-1])
+        u = draw(color)
         new_words = []
         de_planes = []
         acc_planes = []
         for wi, word in enumerate(words):
             # 4-neighbour up-count via a bitwise full adder on rolled planes:
             # count bit-planes (n0, n1, n2) hold cnt = n0 + 2·n1 + 4·n2.
-            up = _roll1(word, 1, 0)
-            dn = _roll1(word, -1, 0)
-            lf = _roll1(word, 1, 1)
-            rt = _roll1(word, -1, 1)
+            up = roll1(word, 1, 0)
+            dn = roll1(word, -1, 0)
+            lf = roll1(word, 1, 1)
+            rt = roll1(word, -1, 1)
             s0, c0 = up ^ dn, up & dn
             s1, c1 = lf ^ rt, lf & rt
             n0 = s0 ^ s1
@@ -310,7 +268,7 @@ def _ising_sweep_body_packed(words, beta, parity, w0, w1, *, j, b, rule):
                     _sel_cnt(b0, b1, b2, pos_tab),
                     _sel_cnt(b0, b1, b2, neg_tab),
                 )
-                accept = (u[i] < _accept_prob(de, beta[i], rule)) & (
+                accept = (u[i] < accept_prob(de, beta[i], rule)) & (
                     parity == color
                 )
                 flips = flips | (accept.astype(jnp.uint32) << kk)
@@ -320,58 +278,9 @@ def _ising_sweep_body_packed(words, beta, parity, w0, w1, *, j, b, rule):
         words = tuple(new_words)
         de = jnp.stack(de_planes)
         accept = jnp.stack(acc_planes)
-        ds = ds + jnp.sum(jnp.where(accept, de, 0.0), axis=(1, 2))
-        na = na + jnp.sum(accept.astype(jnp.int32), axis=(1, 2))
+        ds = ds + site_sum(jnp.where(accept, de, 0.0))
+        na = na + site_sum(accept.astype(jnp.int32))
     return words, ds, na
-
-
-def _ising_sweep_fused_kernel(
-    spins_ref, beta_ref, kw_ref, t0_ref, off_ref, out_ref, de_ref, nacc_ref,
-    *, n_sweeps, r_blk, j, b, rule, pack_bits,
-):
-    """``n_sweeps`` checkerboard sweeps over an (r_blk, L, L) block.
-
-    The spin block stays VMEM-resident across the whole interval; each
-    sweep's uniforms come from the counter PRNG at ``(t0 + sweep, replica,
-    colour)``.  The replica counter is *global*: block offset plus
-    ``off_ref`` (the device's first global slot when the replica axis is
-    sharded), so a device computing slots [off, off+r_local) draws exactly
-    the streams the single-device launch would.  ΔE/acceptance accumulate
-    per replica with the *same association order* as per-sweep oracle
-    application (per-colour within a sweep, then per-sweep), so the f32
-    totals are bit-equal too.  With ``pack_bits`` the in-VMEM spin storage
-    is bit-plane packed along the replica axis (multispin coding); the
-    trajectory is unchanged bitwise.
-    """
-    s = spins_ref[...].astype(jnp.float32)  # widen in VMEM only
-    l = s.shape[-1]
-    parity = _parity(l)
-    beta = beta_ref[...].astype(jnp.float32)
-    sk0, sk1 = prng.stream_key(kw_ref[...])
-    rep = (
-        jax.lax.broadcasted_iota(jnp.uint32, (r_blk,), 0)
-        + (pl.program_id(0) * r_blk).astype(jnp.uint32)
-        + off_ref[0]
-    )
-    t0 = t0_ref[0]
-    body = _ising_sweep_body_packed if pack_bits else _ising_sweep_body
-    carry0 = _pack_spins(s) if pack_bits else s
-
-    def sweep(i, carry):
-        s, de_total, n_acc = carry
-        w0, w1 = prng.sweep_key(sk0, sk1, t0 + i.astype(jnp.uint32), rep)
-        s, ds, na = body(s, beta, parity, w0, w1, j=j, b=b, rule=rule)
-        return s, de_total + ds, n_acc + na
-
-    s, de_total, n_acc = jax.lax.fori_loop(
-        0, n_sweeps, sweep,
-        (carry0, jnp.zeros(r_blk, jnp.float32), jnp.zeros(r_blk, jnp.int32)),
-    )
-    if pack_bits:
-        s = _unpack_spins(s, r_blk)
-    out_ref[...] = s.astype(jnp.int8)
-    de_ref[...] = de_total
-    nacc_ref[...] = n_acc
 
 
 def ising_sweep_fused_pallas(
@@ -407,111 +316,13 @@ def ising_sweep_fused_pallas(
     Returns ``(spins', delta_e, n_accepted)`` with ΔE/acceptance summed over
     the whole interval.
     """
-    r, l, _ = spins.shape
-    assert r % r_blk == 0, (r, r_blk)
     if replica_offset is None:
         replica_offset = jnp.zeros((1,), jnp.uint32)
-    grid = (r // r_blk,)
-    kernel = functools.partial(
-        _ising_sweep_fused_kernel,
-        n_sweeps=n_sweeps, r_blk=r_blk, j=j, b=b, rule=rule,
-        pack_bits=pack_bits,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r_blk, l, l), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r_blk, l, l), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, l, l), jnp.int8),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-        ],
+    return fused_call(
+        _kind(j, b, rule, pack_bits), spins, key_words, t0, betas,
+        n_sweeps=n_sweeps, replica_offset=replica_offset, r_blk=r_blk,
         interpret=interpret,
-    )(spins, betas, key_words, t0, replica_offset)
-
-
-def _ising_round_fused_kernel(
-    spins_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref, energy_ref,
-    out_ref, rung_out_ref, energy_out_ref, nacc_ref, acc_ref, prob_ref,
-    att_ref,
-    *, n_sweeps, n_rounds, r, j, b, rule, criterion, pairing, pack_bits,
-):
-    """``n_rounds`` full PT rounds — sweeps *and* exchange — in one launch.
-
-    Each round is ``n_sweeps`` checkerboard sweeps (the shared
-    `_ising_sweep_body`, at each slot's current rung temperature) followed by
-    one temp-mode DEO/SEO exchange (`exchange.exchange_step`) on the
-    in-VMEM energy row, drawn from the counter PRNG's swap stream at the
-    global swap-phase counter.  The exchange couples every replica, so the
-    whole ladder is one grid step (``grid=(1,)``; no r_blk tiling, no
-    padding) — exactly the regime whole-round fusion targets: R·L² small
-    enough that per-swap kernel exits, not compute, dominate.
-
-    ``beta_ref`` is the (R,) rung-ordered ladder; the per-slot sweep
-    temperature is its one-hot gather at the slot's rung, bitwise the
-    ``betas[rung]`` the interval-fused driver path feeds the sweep kernel.
-    Diagnostics (`accept/prob/attempt` in `core.swap.accept_pairs`
-    conventions) are written per round; int32 stands in for bool on the
-    accept/attempt planes (kernel outputs stay in Mosaic-friendly dtypes).
-    """
-    s = spins_ref[...].astype(jnp.float32)
-    l = s.shape[-1]
-    parity = _parity(l)
-    betas_rung = beta_ref[...].astype(jnp.float32)
-    kw = kw_ref[...]
-    sk0, sk1 = prng.stream_key(kw)
-    rep = jax.lax.broadcasted_iota(jnp.uint32, (r,), 0)
-    t0 = t0_ref[0]
-    ph0 = ph0_ref[0]
-    rung = rung_ref[...]
-    energy = energy_ref[...]
-    body = _ising_sweep_body_packed if pack_bits else _ising_sweep_body
-    carry = _pack_spins(s) if pack_bits else s
-    nacc_total = jnp.zeros(r, jnp.int32)
-
-    for k in range(n_rounds):  # static unroll: one exchange per round
-        beta_slot = _kx.onehot_gather(betas_rung, rung.astype(jnp.int32))
-        t_base = t0 + jnp.uint32(k * n_sweeps)
-
-        def sweep(i, c, _beta=beta_slot, _t=t_base):
-            s, de_total, n_acc = c
-            w0, w1 = prng.sweep_key(sk0, sk1, _t + i.astype(jnp.uint32), rep)
-            s, ds, na = body(s, _beta, parity, w0, w1, j=j, b=b, rule=rule)
-            return s, de_total + ds, n_acc + na
-
-        carry, de_total, na = jax.lax.fori_loop(
-            0, n_sweeps, sweep,
-            (carry, jnp.zeros(r, jnp.float32), jnp.zeros(r, jnp.int32)),
-        )
-        # Same accumulation order as the driver: interval ΔE summed in the
-        # sweep loop, then one f32 add onto the running per-slot energy.
-        energy = energy + de_total
-        nacc_total = nacc_total + na
-        rung, acc, prob, att, _ = _kx.exchange_step(
-            rung, energy, betas_rung, ph0 + jnp.int32(k), kw,
-            pairing=pairing, criterion=criterion,
-        )
-        acc_ref[k, :] = acc.astype(jnp.int32)
-        prob_ref[k, :] = prob
-        att_ref[k, :] = att.astype(jnp.int32)
-
-    if pack_bits:
-        carry = _unpack_spins(carry, r)
-    out_ref[...] = carry.astype(jnp.int8)
-    rung_out_ref[...] = rung
-    energy_out_ref[...] = energy
-    nacc_ref[...] = nacc_total
+    )
 
 
 def ising_round_fused_pallas(
@@ -553,46 +364,11 @@ def ising_round_fused_pallas(
     with the three diagnostic rows shaped (n_rounds, R) (accept/attempt as
     int32 0/1).
     """
-    r, l, _ = spins.shape
-    kernel = functools.partial(
-        _ising_round_fused_kernel,
-        n_sweeps=n_sweeps, n_rounds=n_rounds, r=r, j=j, b=b, rule=rule,
-        criterion=criterion, pairing=pairing, pack_bits=pack_bits,
+    return round_call(
+        _kind(j, b, rule, pack_bits), spins, key_words, t0, phase0, rung,
+        energy, betas, n_sweeps=n_sweeps, n_rounds=n_rounds,
+        criterion=criterion, pairing=pairing, interpret=interpret,
     )
-    row = pl.BlockSpec((r,), lambda i: (0,))
-    diag = pl.BlockSpec((n_rounds, r), lambda i: (0, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(1,),  # the exchange couples all replicas: one grid step
-        in_specs=[
-            pl.BlockSpec((r, l, l), lambda i: (0, 0, 0)),
-            row,
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            row,
-            row,
-        ],
-        out_specs=[
-            pl.BlockSpec((r, l, l), lambda i: (0, 0, 0)),
-            row,
-            row,
-            row,
-            diag,
-            diag,
-            diag,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, l, l), jnp.int8),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((n_rounds, r), jnp.int32),
-            jax.ShapeDtypeStruct((n_rounds, r), jnp.float32),
-            jax.ShapeDtypeStruct((n_rounds, r), jnp.int32),
-        ],
-        interpret=interpret,
-    )(spins, betas, key_words, t0, phase0, rung, energy)
 
 
 def vmem_working_set_bytes(r_blk: int, length: int) -> int:

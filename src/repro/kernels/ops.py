@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles padding to kernel-friendly shapes, dispatch between the Pallas path
-and the pure-jnp oracle (`ref.py`), and platform detection (interpret=True
-everywhere except real TPUs).
+and the pure-jnp oracle (`ref.py`), and the platform: kernels are compiled
+by Mosaic on a TPU and run in interpret mode on the CPU; any other backend
+is refused rather than silently interpreted.
 """
 from __future__ import annotations
 
@@ -19,8 +20,17 @@ from repro.kernels import ref as _ref
 from repro.kernels import wkv6 as _wkv6
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret on the CPU, compile on a TPU, refuse anything else."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU; "
+        f"the default backend is {backend!r}"
+    )
 
 
 def _pad_replicas(arrays, betas, r_blk: int):
@@ -65,7 +75,7 @@ def ising_sweep(
     (spins, u), betas, r = _pad_replicas([spins, u], betas, r_blk)
     out, de, nacc = _ising.ising_sweep_pallas(
         spins, u, betas, j=j, b=b, rule=rule, r_blk=r_blk,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
     return out[:r], de[:r], nacc[:r]
 
@@ -95,7 +105,7 @@ def potts_sweep(
     (states, u), betas, r = _pad_replicas([states, u], betas, r_blk)
     out, de, nacc = _potts.potts_sweep_pallas(
         states, u, betas, q=q, j=j, rule=rule,
-        r_blk=r_blk, interpret=not _on_tpu(),
+        r_blk=r_blk, interpret=_interpret(),
     )
     return out[:r], de[:r], nacc[:r]
 
@@ -134,10 +144,10 @@ def ising_sweep_fused(
     global sweep counter at interval entry; uniforms come from the counter
     PRNG (`repro.kernels.prng`) so the ``use_pallas=False`` pure-JAX path —
     ``n_sweeps`` applications of `ref.ising_sweep` fed
-    `prng.ising_sweep_uniforms` — is bit-exact with the kernel in interpret
-    mode.  Replica padding follows `ising_sweep` (pad rows tile the real
-    lattices at beta=0, dropped on return); real replicas keep counter
-    indices ``offset..offset+R-1``
+    `prng.ising_sweep_uniforms` — matches the kernel exactly in spins and
+    acceptance counts, and in ΔE to f32 rounding.  Replica padding follows
+    `ising_sweep` (pad rows tile the real lattices at beta=0, dropped on
+    return); real replicas keep counter indices ``offset..offset+R-1``
     so the stream is padding-invariant.  ``replica_offset`` (traced uint32
     scalar, default 0) is the global index of local replica 0 when the
     replica axis is sharded across devices: a device holding slots
@@ -168,7 +178,7 @@ def ising_sweep_fused(
     out, de, nacc = _ising.ising_sweep_fused_pallas(
         spins, words, t0, padded_betas, n_sweeps=n_sweeps,
         replica_offset=off, j=j, b=b,
-        rule=rule, r_blk=r_blk, pack_bits=pack_bits, interpret=not _on_tpu(),
+        rule=rule, r_blk=r_blk, pack_bits=pack_bits, interpret=_interpret(),
     )
     return out[:r], de[:r], nacc[:r]
 
@@ -198,8 +208,8 @@ def potts_sweep_fused(
     (including the sharded-replica ``replica_offset`` counter convention).
 
     The ``use_pallas=False`` path applies `ref.potts_sweep` ``n_sweeps``
-    times on `prng.potts_sweep_uniforms` — bit-exact with the fused kernel
-    in interpret mode.
+    times on `prng.potts_sweep_uniforms` — exact in colours and counts, ΔE
+    to f32 rounding, against the fused kernel.
     """
     words, t0 = _fused_prelude(key, t)
     off = jnp.asarray(replica_offset).astype(jnp.uint32).reshape(-1)[:1]
@@ -224,7 +234,7 @@ def potts_sweep_fused(
     out, de, nacc = _potts.potts_sweep_fused_pallas(
         states, words, t0, padded_betas, n_sweeps=n_sweeps, q=q,
         replica_offset=off, j=j,
-        rule=rule, r_blk=r_blk, pack_bits=pack_bits, interpret=not _on_tpu(),
+        rule=rule, r_blk=r_blk, pack_bits=pack_bits, interpret=_interpret(),
     )
     return out[:r], de[:r], nacc[:r]
 
@@ -272,7 +282,8 @@ def ising_round_fused(
     ``betas`` the rung-ordered ladder.  The ``use_pallas=False`` pure-JAX
     reference composes `ising_sweep_fused` (reference mode) with the shared
     `exchange.exchange_step` per round — bit-exact with the kernel in
-    interpret mode (tests/test_fused_round.py pins it).  Keying the swap
+    interpret mode where ΔE is integer-valued (tests/test_fused_round.py
+    pins it), and to f32 rounding in the energies otherwise.  Keying the swap
     stream on ``phase`` makes the trajectory invariant to ``n_rounds``
     launch grouping: K rounds in one launch ≡ K single-round launches.
 
@@ -286,9 +297,8 @@ def ising_round_fused(
         na_total = jnp.zeros((r,), jnp.int32)
         acc_rows, prob_rows, att_rows = [], [], []
         for k in range(n_rounds):
-            beta_slot = _kx.onehot_gather(betas, rung)
             spins, de, na = ising_sweep_fused(
-                spins, key, t0[0] + jnp.uint32(k * n_sweeps), beta_slot,
+                spins, key, t0[0] + jnp.uint32(k * n_sweeps), betas[rung],
                 n_sweeps=n_sweeps, j=j, b=b, rule=rule, use_pallas=False,
             )
             energy = energy + de
@@ -308,7 +318,7 @@ def ising_round_fused(
         spins, words, t0, ph0, rung, energy, betas,
         n_sweeps=n_sweeps, n_rounds=n_rounds, j=j, b=b, rule=rule,
         criterion=criterion, pairing=pairing, pack_bits=pack_bits,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
     return out, rung, energy, nacc, acc.astype(bool), prob, att.astype(bool)
 
@@ -346,9 +356,8 @@ def potts_round_fused(
         na_total = jnp.zeros((r,), jnp.int32)
         acc_rows, prob_rows, att_rows = [], [], []
         for k in range(n_rounds):
-            beta_slot = _kx.onehot_gather(betas, rung)
             states, de, na = potts_sweep_fused(
-                states, key, t0[0] + jnp.uint32(k * n_sweeps), beta_slot,
+                states, key, t0[0] + jnp.uint32(k * n_sweeps), betas[rung],
                 n_sweeps=n_sweeps, q=q, j=j, rule=rule, use_pallas=False,
             )
             energy = energy + de
@@ -368,7 +377,7 @@ def potts_round_fused(
         states, words, t0, ph0, rung, energy, betas,
         n_sweeps=n_sweeps, q=q, n_rounds=n_rounds, j=j, rule=rule,
         criterion=criterion, pairing=pairing, pack_bits=pack_bits,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
     return out, rung, energy, nacc, acc.astype(bool), prob, att.astype(bool)
 
@@ -401,6 +410,6 @@ def wkv6(
         v = jnp.concatenate([v, zv], axis=1)
         w = jnp.concatenate([w, jnp.ones((bh, pad, dk), w.dtype)], axis=1)
     o, s = _wkv6.wkv6_pallas(
-        r, k, v, w, u, initial_state, chunk=chunk, interpret=not _on_tpu()
+        r, k, v, w, u, initial_state, chunk=chunk, interpret=_interpret()
     )
     return o[:, :t], s

@@ -1,16 +1,18 @@
 """Pallas TPU kernel: checkerboard sweep for the q-state Potts model.
 
-Same tile strategy as `repro.kernels.ising_sweep` (DESIGN.md §2/§6): one grid
-step holds a block of ``r_blk`` replicas with their full (H, W) lattices
-resident in VMEM, both colour half-sweeps run back-to-back in-kernel (one HBM
-round-trip of the colour block per sweep), colours are int8 in HBM and widened
-to int32 only inside VMEM.  The proposal randoms ride alongside the
-acceptance randoms as kernel inputs, so the CPU `interpret=True` path is
-bit-exact with `ref.potts_sweep`.
+Same tile strategy as `repro.kernels.ising_sweep` (DESIGN.md §2/§6), on the
+shared launch plumbing of `repro.kernels.lattice`: one grid step moves a
+block of ``r_blk`` replicas with their full (H, W) lattices into VMEM and
+sweeps them one at a time, both colour half-sweeps run back-to-back
+in-kernel (one HBM round-trip of the colour block per sweep), colours are
+int8 in HBM and widened to int32 only inside VMEM.  The proposal randoms
+ride alongside the acceptance randoms as kernel inputs, so the CPU
+`interpret=True` path matches `ref.potts_sweep` exactly in colours and
+acceptance counts (ΔE to f32 rounding).
 
 Like the Ising kernel, the variants share the tile strategy (DESIGN.md §6):
 ``potts_sweep_pallas`` (one sweep per launch, uniforms as an input stream —
-bit-exact vs `ref.potts_sweep`), ``potts_sweep_fused_pallas`` (one swap
+exact vs `ref.potts_sweep`), ``potts_sweep_fused_pallas`` (one swap
 *interval* per launch: all ``n_sweeps`` sweeps with the colour block
 VMEM-resident, the four uniform planes per sweep generated in-kernel by the
 counter PRNG `repro.kernels.prng` at ``(key, sweep, replica, 2*colour +
@@ -28,7 +30,8 @@ through the whole update (proposal, trial, equality comparisons) instead of
 widening to int32 — 4× denser working state, valid for ``q ≤ 64`` (the
 int8 intermediate ``s + d`` peaks at ``2q − 2``).  Comparisons and selects
 on int8 produce the same booleans, so the trajectory is bitwise identical
-(pinned by tests).
+(pinned by tests).  Mosaic on v5e has no int8 vector arithmetic, so this
+mode runs in interpret mode only.
 
 VMEM working set per grid step ≈ r_blk · H · W · (2 int8 in/out + 4·4 u-f32 +
 2·4 i32 working copies + 4 de-f32) = 30·r_blk·H·W bytes — roughly 2.3× the
@@ -45,65 +48,66 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels import exchange as _kx
-from repro.kernels import prng
-
-
-def _roll1(x: jnp.ndarray, shift: int, axis: int) -> jnp.ndarray:
-    """±1 circular shift via slice+concat (lowers on both Mosaic and CPU)."""
-    n = x.shape[axis]
-    if shift == 1:
-        a = jax.lax.slice_in_dim(x, n - 1, n, axis=axis)
-        b = jax.lax.slice_in_dim(x, 0, n - 1, axis=axis)
-    else:  # shift == -1
-        a = jax.lax.slice_in_dim(x, 1, n, axis=axis)
-        b = jax.lax.slice_in_dim(x, 0, 1, axis=axis)
-    return jnp.concatenate([a, b], axis=axis)
+from repro.kernels.lattice import (
+    Kind,
+    accept_prob,
+    fused_call,
+    roll1,
+    round_call,
+    site_sum,
+    sweep_call,
+)
 
 
-def _accept_prob(de, beta, rule):
-    """Mirror of `ref.accept_prob` (kept local: kernel code is self-contained)."""
-    if rule == "metropolis":
-        return jnp.exp(-beta * de)
-    if rule == "glauber":
-        return jax.nn.sigmoid(-beta * de)
-    raise ValueError(rule)
+def _potts_sweep_body(s, beta, parity, draw, *, q, j, rule, packed):
+    """One checkerboard Potts sweep on a colour group, shared by all kernels.
 
-
-def _potts_sweep_kernel(
-    states_ref, u_ref, beta_ref, out_ref, de_ref, nacc_ref, *, q, j, rule
-):
-    """One full checkerboard sweep over an (r_blk, H, W) block."""
-    s = states_ref[...].astype(jnp.int32)  # widen in VMEM only
-    h, w = s.shape[-2], s.shape[-1]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (h, w), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (h, w), 1)
-    parity = (ii + jj) % 2
-    beta = beta_ref[...].astype(jnp.float32)[:, None, None]
-
-    de_total = jnp.zeros(s.shape[0], jnp.float32)
-    n_acc = jnp.zeros(s.shape[0], jnp.int32)
+    ``draw(plane)`` gives the uniforms of plane ``2*colour + (0 proposal |
+    1 accept)``; ``beta`` is a (g, 1, 1) column.  ``packed`` keeps the
+    update in int8 lanes (multispin-style dense storage, q ≤ 64) instead of
+    the int32 working copy; equality comparisons and the accept select
+    produce identical booleans either way, so the two modes are
+    bitwise-identical — only the VMEM working set differs.
+    Returns ``(s', delta_e (g, 1, 1), n_accepted (g, 1, 1))``.
+    """
+    ds = jnp.zeros(beta.shape, jnp.float32)
+    na = jnp.zeros(beta.shape, jnp.int32)
     for color in (0, 1):  # static unroll: two half-sweeps, one HBM round-trip
-        d = 1 + jnp.floor(u_ref[:, color, 0] * (q - 1)).astype(jnp.int32)
-        trial = jax.lax.rem(s + d, q)
+        u_prop = draw(2 * color + 0)
+        u_acc = draw(2 * color + 1)
+        if packed:
+            # int8 lanes throughout: s + d peaks at 2q-2 <= 126 for q <= 64
+            d = 1 + jnp.floor(u_prop * (q - 1)).astype(jnp.int8)
+            trial = jax.lax.rem((s + d).astype(jnp.int8), jnp.int8(q))
+        else:
+            d = 1 + jnp.floor(u_prop * (q - 1)).astype(jnp.int32)
+            trial = jax.lax.rem(s + d, q)
         de = jnp.zeros(s.shape, jnp.float32)
         for axis, shift in ((1, 1), (1, -1), (2, 1), (2, -1)):
-            nbr = _roll1(s, shift, axis)
+            nbr = roll1(s, shift, axis)
             de = de + j * (
-                (s == nbr).astype(jnp.float32) - (trial == nbr).astype(jnp.float32)
+                (s == nbr).astype(jnp.float32)
+                - (trial == nbr).astype(jnp.float32)
             )
-        accept = (u_ref[:, color, 1] < _accept_prob(de, beta, rule)) & (
-            parity == color
-        )
+        accept = (u_acc < accept_prob(de, beta, rule)) & (parity == color)
         s = jnp.where(accept, trial, s)
-        de_total = de_total + jnp.sum(jnp.where(accept, de, 0.0), axis=(1, 2))
-        n_acc = n_acc + jnp.sum(accept.astype(jnp.int32), axis=(1, 2))
+        ds = ds + site_sum(jnp.where(accept, de, 0.0))
+        na = na + site_sum(accept.astype(jnp.int32))
+    return s, ds, na
 
-    out_ref[...] = s.astype(jnp.int8)
-    de_ref[...] = de_total
-    nacc_ref[...] = n_acc
+
+def _kind(q, j, rule, pack_bits=False) -> Kind:
+    if pack_bits and q > 64:
+        raise ValueError(f"pack_bits needs q <= 64 (int8 lanes), got q={q}")
+    dtype = jnp.int8 if pack_bits else jnp.int32
+    return Kind(
+        load=lambda s8: s8.astype(dtype),
+        step=functools.partial(
+            _potts_sweep_body, q=q, j=j, rule=rule, packed=pack_bits
+        ),
+        store=lambda s, _n: s.astype(jnp.int8),
+    )
 
 
 def potts_sweep_pallas(
@@ -126,120 +130,14 @@ def potts_sweep_pallas(
       betas: (R,) f32.
       q: number of colours (static).
       r_blk: replicas per grid step (the Fig.-6 "block size" analogue).
-      interpret: True on CPU (bit-exact vs the oracle); False on real TPU.
+      interpret: True on CPU; False on real TPU.
     """
     r, h, w = states.shape
-    assert r % r_blk == 0, (r, r_blk)
-    grid = (r // r_blk,)
-    kernel = functools.partial(_potts_sweep_kernel, q=q, j=j, rule=rule)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r_blk, h, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk, 2, 2, h, w), lambda i: (i, 0, 0, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r_blk, h, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, h, w), jnp.int8),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-        ],
+    planes = u.reshape(r, 4, h, w)  # plane 2*colour + (proposal | accept)
+    return sweep_call(
+        _kind(q, j, rule), states, planes, betas, r_blk=r_blk,
         interpret=interpret,
-    )(states, u, betas)
-
-
-def _parity(h: int, w: int) -> jnp.ndarray:
-    """(h, w) checkerboard colour map from 2-D iotas (Mosaic-safe)."""
-    ii = jax.lax.broadcasted_iota(jnp.int32, (h, w), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (h, w), 1)
-    return (ii + jj) % 2
-
-
-def _potts_sweep_body(s, beta, parity, w0, w1, *, q, j, rule, packed):
-    """One checkerboard Potts sweep on the in-VMEM colour block.
-
-    Shared by the interval-fused and whole-round kernels.  ``packed`` keeps
-    the update in int8 lanes (multispin-style dense storage, q ≤ 64)
-    instead of the int32 working copy; equality comparisons and the accept
-    select produce identical booleans either way, so the two modes are
-    bitwise-identical — only the VMEM working set differs.
-    Returns ``(s', delta_e (r,), n_accepted (r,))``.
-    """
-    h, w = parity.shape
-    beta3 = beta[:, None, None]
-    ds = jnp.zeros(s.shape[0], jnp.float32)
-    na = jnp.zeros(s.shape[0], jnp.int32)
-    for color in (0, 1):  # static unroll, exactly as the per-sweep kernel
-        u_prop = prng.plane_uniforms(w0, w1, 2 * color + 0, h, w)
-        u_acc = prng.plane_uniforms(w0, w1, 2 * color + 1, h, w)
-        if packed:
-            # int8 lanes throughout: s + d peaks at 2q-2 <= 126 for q <= 64
-            d = 1 + jnp.floor(u_prop * (q - 1)).astype(jnp.int8)
-            trial = jax.lax.rem((s + d).astype(jnp.int8), jnp.int8(q))
-        else:
-            d = 1 + jnp.floor(u_prop * (q - 1)).astype(jnp.int32)
-            trial = jax.lax.rem(s + d, q)
-        de = jnp.zeros(s.shape, jnp.float32)
-        for axis, shift in ((1, 1), (1, -1), (2, 1), (2, -1)):
-            nbr = _roll1(s, shift, axis)
-            de = de + j * (
-                (s == nbr).astype(jnp.float32)
-                - (trial == nbr).astype(jnp.float32)
-            )
-        accept = (u_acc < _accept_prob(de, beta3, rule)) & (parity == color)
-        s = jnp.where(accept, trial, s)
-        ds = ds + jnp.sum(jnp.where(accept, de, 0.0), axis=(1, 2))
-        na = na + jnp.sum(accept.astype(jnp.int32), axis=(1, 2))
-    return s, ds, na
-
-
-def _potts_sweep_fused_kernel(
-    states_ref, beta_ref, kw_ref, t0_ref, off_ref, out_ref, de_ref, nacc_ref,
-    *, n_sweeps, r_blk, q, j, rule, pack_bits,
-):
-    """``n_sweeps`` checkerboard Potts sweeps over an (r_blk, H, W) block.
-
-    Same interval-fusion scheme as `_ising_sweep_fused_kernel`: the colour
-    block stays VMEM-resident, per-sweep uniforms come from the counter PRNG
-    (plane ``2*colour + (0 proposal | 1 accept)``) keyed on the *global*
-    replica counter (block offset + ``off_ref`` under replica-axis sharding),
-    and ΔE/acceptance accumulate in the per-sweep oracle's association order
-    (bit-equal f32).  ``pack_bits`` keeps the lattice in int8 lanes instead
-    of widening to int32 (same trajectory bitwise, q ≤ 64).
-    """
-    s = states_ref[...] if pack_bits else states_ref[...].astype(jnp.int32)
-    h, w = s.shape[-2], s.shape[-1]
-    parity = _parity(h, w)
-    beta = beta_ref[...].astype(jnp.float32)
-    sk0, sk1 = prng.stream_key(kw_ref[...])
-    rep = (
-        jax.lax.broadcasted_iota(jnp.uint32, (r_blk,), 0)
-        + (pl.program_id(0) * r_blk).astype(jnp.uint32)
-        + off_ref[0]
     )
-    t0 = t0_ref[0]
-
-    def sweep(i, carry):
-        s, de_total, n_acc = carry
-        w0, w1 = prng.sweep_key(sk0, sk1, t0 + i.astype(jnp.uint32), rep)
-        s, ds, na = _potts_sweep_body(
-            s, beta, parity, w0, w1, q=q, j=j, rule=rule, packed=pack_bits
-        )
-        return s, de_total + ds, n_acc + na
-
-    s, de_total, n_acc = jax.lax.fori_loop(
-        0, n_sweeps, sweep,
-        (s, jnp.zeros(r_blk, jnp.float32), jnp.zeros(r_blk, jnp.int32)),
-    )
-    out_ref[...] = s.astype(jnp.int8)
-    de_ref[...] = de_total
-    nacc_ref[...] = n_acc
 
 
 def potts_sweep_fused_pallas(
@@ -271,102 +169,13 @@ def potts_sweep_fused_pallas(
 
     Returns ``(states', delta_e, n_accepted)`` summed over the interval.
     """
-    r, h, w = states.shape
-    assert r % r_blk == 0, (r, r_blk)
-    if pack_bits and q > 64:
-        raise ValueError(f"pack_bits needs q <= 64 (int8 lanes), got q={q}")
+    kind = _kind(q, j, rule, pack_bits)
     if replica_offset is None:
         replica_offset = jnp.zeros((1,), jnp.uint32)
-    grid = (r // r_blk,)
-    kernel = functools.partial(
-        _potts_sweep_fused_kernel,
-        n_sweeps=n_sweeps, r_blk=r_blk, q=q, j=j, rule=rule,
-        pack_bits=pack_bits,
+    return fused_call(
+        kind, states, key_words, t0, betas, n_sweeps=n_sweeps,
+        replica_offset=replica_offset, r_blk=r_blk, interpret=interpret,
     )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r_blk, h, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((r_blk, h, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-            pl.BlockSpec((r_blk,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, h, w), jnp.int8),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(states, betas, key_words, t0, replica_offset)
-
-
-def _potts_round_fused_kernel(
-    states_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref, energy_ref,
-    out_ref, rung_out_ref, energy_out_ref, nacc_ref, acc_ref, prob_ref,
-    att_ref,
-    *, n_sweeps, n_rounds, r, q, j, rule, criterion, pairing, pack_bits,
-):
-    """``n_rounds`` full PT rounds (sweeps + temp-mode exchange) per launch.
-
-    Potts analogue of `_ising_round_fused_kernel` (see that docstring):
-    whole ladder in one grid step, per-slot sweep temperature one-hot
-    gathered from the rung-ordered ladder each round, exchange via the
-    shared `exchange.exchange_step` on the in-VMEM energy row at the global
-    swap-phase counter.
-    """
-    s = states_ref[...] if pack_bits else states_ref[...].astype(jnp.int32)
-    h, w = s.shape[-2], s.shape[-1]
-    parity = _parity(h, w)
-    betas_rung = beta_ref[...].astype(jnp.float32)
-    kw = kw_ref[...]
-    sk0, sk1 = prng.stream_key(kw)
-    rep = jax.lax.broadcasted_iota(jnp.uint32, (r,), 0)
-    t0 = t0_ref[0]
-    ph0 = ph0_ref[0]
-    rung = rung_ref[...]
-    energy = energy_ref[...]
-    nacc_total = jnp.zeros(r, jnp.int32)
-
-    for k in range(n_rounds):  # static unroll: one exchange per round
-        beta_slot = _kx.onehot_gather(betas_rung, rung)
-        t_base = t0 + jnp.uint32(k * n_sweeps)
-
-        def sweep(i, c, _beta=beta_slot, _t=t_base):
-            s, de_total, n_acc = c
-            w0, w1 = prng.sweep_key(sk0, sk1, _t + i.astype(jnp.uint32), rep)
-            s, ds, na = _potts_sweep_body(
-                s, _beta, parity, w0, w1, q=q, j=j, rule=rule,
-                packed=pack_bits,
-            )
-            return s, de_total + ds, n_acc + na
-
-        s, de_total, na = jax.lax.fori_loop(
-            0, n_sweeps, sweep,
-            (s, jnp.zeros(r, jnp.float32), jnp.zeros(r, jnp.int32)),
-        )
-        # Same accumulation order as the driver: interval ΔE summed in the
-        # sweep loop, then one f32 add onto the running per-slot energy.
-        energy = energy + de_total
-        nacc_total = nacc_total + na
-        rung, acc, prob, att, _ = _kx.exchange_step(
-            rung, energy, betas_rung, ph0 + jnp.int32(k), kw,
-            pairing=pairing, criterion=criterion,
-        )
-        acc_ref[k, :] = acc.astype(jnp.int32)
-        prob_ref[k, :] = prob
-        att_ref[k, :] = att.astype(jnp.int32)
-
-    out_ref[...] = s.astype(jnp.int8)
-    rung_out_ref[...] = rung
-    energy_out_ref[...] = energy
-    nacc_ref[...] = nacc_total
 
 
 def potts_round_fused_pallas(
@@ -395,48 +204,12 @@ def potts_round_fused_pallas(
     accept, prob, attempt)`` with diagnostics shaped (n_rounds, R)
     (accept/attempt as int32 0/1).
     """
-    r, h, w = states.shape
-    if pack_bits and q > 64:
-        raise ValueError(f"pack_bits needs q <= 64 (int8 lanes), got q={q}")
-    kernel = functools.partial(
-        _potts_round_fused_kernel,
-        n_sweeps=n_sweeps, n_rounds=n_rounds, r=r, q=q, j=j, rule=rule,
-        criterion=criterion, pairing=pairing, pack_bits=pack_bits,
+    kind = _kind(q, j, rule, pack_bits)
+    return round_call(
+        kind, states, key_words, t0, phase0, rung, energy, betas,
+        n_sweeps=n_sweeps, n_rounds=n_rounds, criterion=criterion,
+        pairing=pairing, interpret=interpret,
     )
-    row = pl.BlockSpec((r,), lambda i: (0,))
-    diag = pl.BlockSpec((n_rounds, r), lambda i: (0, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(1,),  # the exchange couples all replicas: one grid step
-        in_specs=[
-            pl.BlockSpec((r, h, w), lambda i: (0, 0, 0)),
-            row,
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            row,
-            row,
-        ],
-        out_specs=[
-            pl.BlockSpec((r, h, w), lambda i: (0, 0, 0)),
-            row,
-            row,
-            row,
-            diag,
-            diag,
-            diag,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, h, w), jnp.int8),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((n_rounds, r), jnp.int32),
-            jax.ShapeDtypeStruct((n_rounds, r), jnp.float32),
-            jax.ShapeDtypeStruct((n_rounds, r), jnp.int32),
-        ],
-        interpret=interpret,
-    )(states, betas, key_words, t0, phase0, rung, energy)
 
 
 def vmem_working_set_bytes(r_blk: int, height: int, width: int) -> int:

@@ -48,6 +48,8 @@ __all__ = [
     "key_words",
     "stream_key",
     "sweep_key",
+    "iota_u32",
+    "to_uniform",
     "plane_uniforms",
     "ising_sweep_uniforms",
     "potts_sweep_uniforms",
@@ -131,21 +133,35 @@ def sweep_key(s0, s1, t, replica):
     return threefry2x32(s0, s1, t, replica)
 
 
-def plane_uniforms(w0, w1, plane: int, h: int, w: int) -> jnp.ndarray:
-    """(..., h, w) f32 uniforms in [0,1) for one random lattice ("plane").
+def iota_u32(shape, dimension: int) -> jnp.ndarray:
+    """uint32 `broadcasted_iota`, built as int32 and bitcast (Mosaic-safe)."""
+    return jax.lax.bitcast_convert_type(
+        jax.lax.broadcasted_iota(jnp.int32, shape, dimension), jnp.uint32
+    )
 
-    ``w0``/``w1`` are per-replica sweep-key words shaped (...,) — typically
-    (R,); the site counter is the linear index ``i*w + j`` so the stream is
+
+def to_uniform(bits: jnp.ndarray) -> jnp.ndarray:
+    """Top 24 bits of uint32 draws as f32 in [0, 1).
+
+    ``bits >> 8`` is below 2^24, so it converts exactly through int32 —
+    Mosaic has no uint32 -> f32 cast, and the value is the same either way.
+    """
+    top = jax.lax.bitcast_convert_type(bits >> jnp.uint32(8), jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+
+
+def plane_uniforms(w0, w1, plane: int, h: int, w: int) -> jnp.ndarray:
+    """(R, h, w) f32 uniforms in [0,1) for one random lattice ("plane").
+
+    ``w0``/``w1`` are per-replica sweep-key words shaped (R, 1, 1), which
+    broadcast against the lattice without a reshape inside a kernel body;
+    the site counter is the linear index ``i*w + j`` so the stream is
     layout-independent (padding W for TPU lanes would not change values at
     real sites).
     """
-    ii = jax.lax.broadcasted_iota(jnp.uint32, (h, w), 0)
-    jj = jax.lax.broadcasted_iota(jnp.uint32, (h, w), 1)
-    site = ii * jnp.uint32(w) + jj
-    b0, _ = threefry2x32(
-        w0[..., None, None], w1[..., None, None], jnp.uint32(plane), site
-    )
-    return (b0 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    site = iota_u32((h, w), 0) * jnp.uint32(w) + iota_u32((h, w), 1)
+    b0, _ = threefry2x32(w0, w1, jnp.uint32(plane), site)
+    return to_uniform(b0)
 
 
 # -- pure-JAX per-sweep stream (the oracle's view of the kernel stream) --------
@@ -157,11 +173,17 @@ def ising_sweep_uniforms(words, t, replica_ids, length: int) -> jnp.ndarray:
     Feeding this to `ref.ising_sweep` for t = t0..t0+S-1 reproduces
     ``ising_sweep_fused`` over S sweeps bit-for-bit (spins and counters).
     """
-    s0, s1 = stream_key(words)
-    w0, w1 = sweep_key(s0, s1, jnp.uint32(t), jnp.asarray(replica_ids, jnp.uint32))
+    w0, w1 = _replica_keys(words, t, replica_ids)
     return jnp.stack(
         [plane_uniforms(w0, w1, c, length, length) for c in (0, 1)], axis=1
     )
+
+
+def _replica_keys(words, t, replica_ids):
+    """(R, 1, 1) sweep-key words for sweep ``t`` of each listed replica."""
+    s0, s1 = stream_key(words)
+    rep = jnp.asarray(replica_ids, jnp.uint32).reshape(-1, 1, 1)
+    return sweep_key(s0, s1, jnp.uint32(t), rep)
 
 
 # -- counter-based exchange stream (the in-kernel swap draw) -------------------
@@ -192,18 +214,20 @@ def swap_key(s0, s1, phase):
     return threefry2x32(s0, s1, jnp.asarray(phase, jnp.uint32), jnp.uint32(0))
 
 
-def swap_uniforms(words: jnp.ndarray, phase, n: int) -> jnp.ndarray:
-    """(n,) f32 in [0,1): one acceptance uniform per rung for swap ``phase``.
+def swap_uniforms(words: jnp.ndarray, phase, n: int, shape=None) -> jnp.ndarray:
+    """f32 in [0,1): one acceptance uniform per rung for swap ``phase``.
 
     Same top-24-bit scaling as `plane_uniforms`; the counter is the rung
     index, so the draw at rung r is independent of R (ladder growth never
-    perturbs existing rungs' streams).
+    perturbs existing rungs' streams).  The result is shaped (n,), or
+    ``shape`` with the rungs on its leading axis — the (n, 1, 1) column the
+    kernel-side exchange works in.
     """
     s0, s1 = swap_stream_key(words)
     w0, w1 = swap_key(s0, s1, phase)
-    rung = jax.lax.broadcasted_iota(jnp.uint32, (n,), 0)
+    rung = iota_u32(shape or (n,), 0)
     b0, _ = threefry2x32(w0, w1, jnp.uint32(0), rung)
-    return (b0 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    return to_uniform(b0)
 
 
 def seo_coin(words: jnp.ndarray, phase) -> jnp.ndarray:
@@ -211,14 +235,13 @@ def seo_coin(words: jnp.ndarray, phase) -> jnp.ndarray:
     s0, s1 = swap_stream_key(words)
     w0, w1 = swap_key(s0, s1, phase)
     b0, _ = threefry2x32(w0, w1, jnp.uint32(1), jnp.uint32(0))
-    return (b0 & jnp.uint32(1)).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type(b0 & jnp.uint32(1), jnp.int32)
 
 
 def potts_sweep_uniforms(words, t, replica_ids, h: int, w: int) -> jnp.ndarray:
     """(R, 2, 2, H, W) f32 — the Potts sweep-``t`` uniforms (colour x
     (proposal, accept)); plane index is ``2*colour + which``."""
-    s0, s1 = stream_key(words)
-    w0, w1 = sweep_key(s0, s1, jnp.uint32(t), jnp.asarray(replica_ids, jnp.uint32))
+    w0, w1 = _replica_keys(words, t, replica_ids)
     return jnp.stack(
         [
             jnp.stack(

@@ -1,7 +1,10 @@
 """Pure-jnp oracles for the Pallas kernels.
 
 These are the *numerical contracts*: each kernel in this package must match
-its oracle bit-for-bit in f32 (tests/test_kernels_*.py sweep shapes/dtypes).
+its oracle bit-for-bit in lattices and acceptance counts, and in the f32 ΔE
+totals to rounding — a lattice sum's reduction order is the backend's own
+(tests/test_kernels.py sweeps shapes/dtypes; `chip_smoke.py` checks the
+same on the chip).
 They are also used as the production XLA fallback paths (e.g. lattices too
 large for VMEM-resident tiles, or CPU execution).
 """
@@ -43,7 +46,7 @@ def ising_sweep(
       spins: (R, L, L) int8 in {-1, +1}.
       u: (R, 2, L, L) float32 uniforms in [0, 1) — one lattice of randoms per
         colour half-sweep.  Randoms are *inputs* (not generated in-kernel) so
-        the Pallas kernel and this oracle are bit-exact on CPU (DESIGN.md §6).
+        the Pallas kernel and this oracle agree exactly (DESIGN.md §6).
       betas: (R,) float32 inverse temperatures.
       rule: per-site acceptance rule (see `accept_prob`).
 
@@ -95,7 +98,7 @@ def potts_sweep(
       states: (R, H, W) int8 colours in {0..q-1}.
       u: (R, 2, 2, H, W) float32 uniforms in [0, 1) — axis 1 is the colour
         half-sweep, axis 2 is (proposal draw, acceptance draw).  Randoms are
-        inputs so the Pallas kernel and this oracle are bit-exact on CPU
+        inputs so the Pallas kernel and this oracle agree exactly
         (DESIGN.md §6).
       betas: (R,) float32 inverse temperatures.
       q: number of colours (static).

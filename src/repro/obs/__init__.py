@@ -81,17 +81,16 @@ class Observability:
 
     # -- one-shot jax.profiler window ------------------------------------------
     def start_jax_profile(self) -> bool:
-        """Open the profiler window if armed and unused; True if opened."""
+        """Open the profiler window if armed and unused; True if opened.
+
+        A profile that was asked for and cannot start fails the run: a run
+        that silently carried on would report a profile that does not exist.
+        """
         if self.jax_profile_dir is None or self._jax_profiling:
             return False
         import jax
 
-        try:
-            jax.profiler.start_trace(self.jax_profile_dir)
-        except Exception as e:  # profiler backends vary; never kill the run
-            self.timeline.instant("jax_profile_failed", error=repr(e))
-            self.jax_profile_dir = None
-            return False
+        jax.profiler.start_trace(self.jax_profile_dir)
         self._jax_profiling = True
         self.timeline.instant("jax_profile_start", dir=self.jax_profile_dir)
         return True

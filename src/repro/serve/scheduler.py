@@ -113,6 +113,9 @@ class Scheduler:
       faults: an optional `repro.resilience.FaultPlan` threaded through
         every engine, checkpoint manager and bucket this scheduler builds
         (chaos testing; None in production — zero-cost-off).
+      strict_kernels: make a failed fused/Pallas compile fatal in every
+        engine this scheduler builds instead of degrading to the per-sweep
+        path (``repro serve --strict-kernels``).
 
     Use either synchronously (``submit(...)`` then ``run_until_idle()``) or
     as a service (``start()`` spawns the host loop thread; ``submit`` is
@@ -134,6 +137,7 @@ class Scheduler:
         watchdog_s: float = 0.0,
         queue_depth: int = 0,
         faults=None,
+        strict_kernels: bool = False,
     ):
         if quantum_chunks < 1:
             raise ValueError("quantum_chunks must be >= 1")
@@ -143,6 +147,7 @@ class Scheduler:
         self.checkpoint_every_quanta = checkpoint_every_quanta
         self.keep = keep
         self._faults = faults
+        self._strict_kernels = strict_kernels
         self._supervisor = Supervisor(
             policy=RetryPolicy(
                 max_attempts=max_attempts, base_delay_s=retry_backoff_s
@@ -310,6 +315,7 @@ class Scheduler:
                 # same trace as the quantum lanes
                 obs=self._obs,
                 faults=self._faults,
+                strict_kernels=self._strict_kernels,
                 # obs-on engines count degradations themselves (into the
                 # same registry); the hook covers the obs-off path only —
                 # both would double-count

@@ -8,6 +8,7 @@ Session-vs-raw-Engine bit-equality contract, the callback pipeline
 """
 import json
 import os
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -477,6 +478,29 @@ def test_cli_run_writes_manifest_and_reproduces_session(tmp_path, capsys):
     assert (out / "checkpoints" / "spec.json").exists()
     # manifest path printed on stdout (shell-composable)
     assert capsys.readouterr().out.strip().endswith("manifest.json")
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir_is_fixed_or_the_env_one(monkeypatch, tmp_path, env_dir):
+    """Unset, the cache goes to <checkout>/.jax_cache; set, only there."""
+    from repro.compile_cache import DEFAULT_DIR, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(DEFAULT_DIR)
+        assert DEFAULT_DIR == Path(__file__).resolve().parents[1] / ".jax_cache"
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        monkeypatch.setattr(jax.config, "update", pytest.fail)  # sets nothing
+    try:
+        assert enable_compile_cache() == want
+        if env_dir is None:
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        monkeypatch.undo()
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_cli_list_systems(capsys):

@@ -195,8 +195,11 @@ def test_round_launch_split_invariance():
 @pytest.mark.parametrize("r,r_blk", [(3, 8), (6, 4), (8, 8), (33, 64)])
 def test_ising_packed_interval_bit_equal(r, r_blk):
     """pack_bits is storage only: bit-plane multispin updates must reproduce
-    the unpacked fused kernel bit-for-bit — including pad > R tiles and a
-    block wide enough (r_blk=64) to need a second uint32 bit-plane word."""
+    the unpacked fused kernel's spins and acceptance counts bit-for-bit —
+    including pad > R tiles and a block wide enough (r_blk=64) to need a
+    second uint32 bit-plane word.  The packed kernel sums ΔE over the whole
+    block at once and the unpacked one replica by replica, so the f32 ΔE
+    totals agree to rounding."""
     key = jax.random.key(60 + r)
     spins, betas = _rand_ising(key, r, L)
     kw = dict(n_sweeps=3, j=1.0, b=0.3, r_blk=r_blk, use_pallas=True)
@@ -204,8 +207,11 @@ def test_ising_packed_interval_bit_equal(r, r_blk):
     packed = ops.ising_sweep_fused(
         spins, key, jnp.int32(7), betas, pack_bits=True, **kw
     )
-    for a, b in zip(packed, plain):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(packed[0]), np.asarray(plain[0]))
+    np.testing.assert_allclose(
+        np.asarray(packed[1]), np.asarray(plain[1]), rtol=1e-6, atol=1e-3
+    )
+    np.testing.assert_array_equal(np.asarray(packed[2]), np.asarray(plain[2]))
 
 
 @pytest.mark.parametrize("r,r_blk,q", [(3, 8, 3), (5, 2, 5)])

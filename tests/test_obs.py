@@ -368,6 +368,20 @@ def test_obs_detach_restores_bare_engine():
     assert eng._eobs is None and eng.obs is None
 
 
+def test_failed_jax_profile_start_fails_the_run(monkeypatch, tmp_path):
+    """A profile that was asked for and cannot start must stop the run,
+    not leave a timeline instant behind and carry on unprofiled."""
+    def refuse(_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    obs = Observability.create(timeline=True, jax_profile_dir=str(tmp_path))
+    eng = _engine(obs=obs)
+    st = eng.init(jax.random.key(1), TEMPS)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        eng.run(st, 4)
+
+
 # ---------- obs-on engine instrumentation ---------------------------------------
 
 

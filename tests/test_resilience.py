@@ -21,6 +21,7 @@ The contracts pinned here:
   typed `SchedulerStopped` failure, and a bounded intake queue rejects with
   `QueueFull` instead of accepting unbounded work.
 """
+import dataclasses
 import json
 import os
 import warnings
@@ -324,6 +325,28 @@ def test_strict_kernels_makes_compile_failure_fatal():
     st = eng.init(jax.random.key(0), np.geomspace(1.5, 3.5, 4))
     with pytest.raises(InjectedFault):
         eng.run(st, 8)
+
+
+@pytest.mark.parametrize("strict", [False, True])
+def test_scheduler_strict_kernels_reaches_its_engines(strict):
+    """A Scheduler built with strict_kernels fails a job whose fused
+    compile fails, where the default degrades it to the per-sweep path."""
+    plan = FaultPlan([Fault("engine.compile", at=(0,))])
+    spec = dataclasses.replace(
+        serve_spec(sweeps=(8,)),
+        system=SystemSpec("ising", {"length": 4, "use_fused": True}),
+    )
+    sched = Scheduler(max_attempts=1, retry_backoff_s=0.0, faults=plan,
+                      strict_kernels=strict)
+    job = sched.submit(spec)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        sched.run_until_idle()
+    if strict:
+        with pytest.raises(JobFailedError):
+            sched.result(job, timeout=0)
+    else:
+        assert sched.result(job, timeout=0).final_energy is not None
 
 
 def test_plain_system_compile_failure_propagates():

@@ -149,7 +149,6 @@ def test_error_feedback_reduces_bias():
 
 
 def test_compressed_psum_matches_full_precision():
-    import jax.experimental.shard_map as shmap
     from jax.sharding import Mesh, PartitionSpec as P
 
     n = jax.device_count()  # 1 on CPU: still exercises the code path
@@ -160,7 +159,7 @@ def test_compressed_psum_matches_full_precision():
     def f(g, err):
         return gc.compressed_psum(g, err, "d")
 
-    out, new_err = shmap.shard_map(
+    out, new_err = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P())
     )(g, err)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g) * n, atol=2e-2)
