@@ -217,7 +217,6 @@ def _cmd_serve(args) -> int:
         max_attempts=args.max_attempts,
         watchdog_s=args.watchdog_s,
         queue_depth=args.queue_depth,
-        strict_kernels=args.strict_kernels,
     )
     handles = []
     for path in args.specs:
@@ -379,9 +378,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--queue-depth", type=int, default=0,
                    help="bound the intake queue (QueueFull backpressure); "
                         "0 = unbounded")
-    p.add_argument("--strict-kernels", action="store_true",
-                   help="fail a job if a fused/Pallas mega-step compile "
-                        "errors instead of degrading to the per-sweep path")
     p.add_argument("--quiet", action="store_true")
     p.set_defaults(fn=_cmd_serve)
 

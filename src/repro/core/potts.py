@@ -82,7 +82,8 @@ class PottsSystem:
         `repro.core.ising.IsingSystem` for the stream contract).
       pack_bits: fused paths only — keep the lattice in dense int8 lanes
         in-kernel instead of widening to int32 (bitwise-identical; needs
-        q ≤ 64).
+        q ≤ 64; refused with ``use_pallas`` on a TPU, which cannot compile
+        int8 vector arithmetic).
       accept_rule: "metropolis" or "glauber" (see repro.kernels.ref).
       r_blk: replicas per Pallas grid step; 4 is the documented VMEM-safe
         block at the paper's L=300 (`kernels.potts_sweep`).
@@ -114,9 +115,15 @@ class PottsSystem:
                 "kernel is the interval-fused kernel plus an in-kernel "
                 "exchange)"
             )
-        if self.pack_bits and self.q > 64:
-            raise ValueError(
-                f"pack_bits needs q <= 64 (int8 lanes), got q={self.q}"
+        if self.pack_bits:
+            from repro.kernels import ops as kops
+            from repro.kernels.potts_sweep import check_pack_bits
+
+            # refused at build time: a kernel that cannot compile would
+            # otherwise degrade the run to the per-sweep path
+            check_pack_bits(
+                self.q,
+                interpret=kops._interpret() if self.use_pallas else True,
             )
 
     # -- System protocol ---------------------------------------------------

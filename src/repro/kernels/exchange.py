@@ -93,9 +93,15 @@ def onehot_gather(values: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     gathered value (a ``-0.0`` entry surfaces as ``+0.0`` — value-equal,
     and impossible for the betas/energies this module gathers).
     """
-    n = values.shape[0]
-    zero = jnp.zeros((), values.dtype)
-    return jnp.sum(jnp.where(idx == _row(n), to_row(values), zero), axis=2,
+    return gather_row(to_row(values), idx)
+
+
+def gather_row(row: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """`onehot_gather` from a (1, 1, n) `to_row` row: O(n) per index, so a
+    caller gathering from the same values many times builds the row once."""
+    n = row.shape[2]
+    zero = jnp.zeros((), row.dtype)
+    return jnp.sum(jnp.where(idx == _row(n), row, zero), axis=2,
                    keepdims=True)
 
 

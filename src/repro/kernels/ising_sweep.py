@@ -41,9 +41,11 @@ Three kernels share that tile strategy (DESIGN.md §6):
   per-swap kernel exit + host round-trip entirely; with ``n_rounds > 1``
   the spin block stays VMEM-resident across multiple exchanges.
 
-ΔE totals are f32 sums over the lattice.  The order in which a backend
-reduces them is its own, so they agree with the oracle to rounding, not
-bit for bit; spins and acceptance counts are exact.
+ΔE totals come from integer-valued sums over the flipped sites (Σ s·nbr
+and Σ s; `_flip_energy`), exact in any reduction order up to 2^23 sites,
+so every backend and both storage layouts give the same bits.  The
+oracle sums f32 per-site values in its backend's own order, so kernel and
+oracle ΔE agree to rounding; spins and acceptance counts are exact.
 
 All fused variants take ``pack_bits``: bit-plane **multispin packing** of
 the replica axis (Weigel, arXiv:1004.0023) — spins live as 1 bit per
@@ -93,6 +95,21 @@ def _narrow(s: jnp.ndarray) -> jnp.ndarray:
     return s.astype(jnp.int32).astype(jnp.int8)
 
 
+def _flip_energy(s_nbr, s_sum, j, b):
+    """ΔE of one colour's flips, Σ 2s(j·nbr − b), from its integer sums.
+
+    ``s_nbr`` is Σ s·nbr and ``s_sum`` is Σ s over the flipped sites (f32
+    sums of integer values; ``s_sum`` unused when b = 0).  Every partial
+    sum is an integer of magnitude ≤ 4·H·W/2, so for lattices of up to
+    2^23 sites (L ≤ 2896) they are exact in any order, and every backend
+    and storage layout gives the same total.
+    """
+    de = j * s_nbr
+    if b:
+        de = de - b * s_sum
+    return 2.0 * de
+
+
 def _ising_sweep_body(s, beta, parity, draw, *, j, b, rule):
     """One checkerboard sweep (two half-sweeps) on a widened f32 spin group.
 
@@ -107,9 +124,11 @@ def _ising_sweep_body(s, beta, parity, draw, *, j, b, rule):
         nbr = roll1(s, 1, 1) + roll1(s, -1, 1) + roll1(s, 1, 2) + roll1(s, -1, 2)
         de = 2.0 * s * (j * nbr - b)
         accept = (u < accept_prob(de, beta, rule)) & (parity == color)
-        s = jnp.where(accept, -s, s)
-        ds = ds + site_sum(jnp.where(accept, de, 0.0))
+        flipped = jnp.where(accept, s, 0.0)
+        s_sum = site_sum(flipped) if b else None
+        ds = ds + _flip_energy(site_sum(flipped * nbr), s_sum, j, b)
         na = na + site_sum(accept.astype(jnp.int32))
+        s = jnp.where(accept, -s, s)
     return s, ds, na
 
 
@@ -162,11 +181,10 @@ def ising_sweep_pallas(
 # bitwise full-adder tree over the 4 rolled word planes, and ΔE is selected
 # per replica from the 10 possible values (s ∈ {−1,+1} × count ∈ 0..4) by
 # nested `where`s on the count's 3 bit-planes.  The table entries are built
-# with the *same f32 op sequence* as the unpacked ``2.0 * s * (j*nbr - b)``
-# and the accept/ΔE planes are restacked to (r, l, l) before the *same* sum
-# reductions, so the packed path's spins and counts are bit-equal to the
-# unpacked one's, and its ΔE equal to rounding (the packed kernel reduces
-# the whole block at once) — pinned by tests/test_fused_round.py.
+# with the *same f32 op sequence* as the unpacked ``2.0 * s * (j*nbr - b)``,
+# so acceptances match; ΔE comes from the same exact integer sums
+# (`_flip_energy`), so the packed path is bit-equal to the unpacked one —
+# pinned by tests/test_fused_round.py.
 
 
 def _pack_spins(s: jnp.ndarray):
@@ -208,10 +226,13 @@ def _sel_cnt(n0, n1, n2, vals):
     return jnp.where(n2 > 0, vals[4], x)
 
 
+_NBR = [2 * cnt - 4 for cnt in range(5)]  # neighbour sum of an up-count
+
+
 def _ising_de_tables(j, b):
     """ΔE(s, count) lookup rows, one per spin sign, f32-op-identical.
 
-    Entry ``cnt`` is ``2.0 * s * (j * nbr - b)`` with ``nbr = 2·cnt − 4``,
+    Entry ``cnt`` is ``2.0 * s * (j * nbr - b)`` with ``nbr = _NBR[cnt]``,
     evaluated with the same jnp f32 op order as the unpacked body so the
     selected values match it bitwise.
     """
@@ -219,7 +240,7 @@ def _ising_de_tables(j, b):
     for sv in (-1.0, 1.0):
         s = jnp.float32(sv)
         rows[sv] = [
-            2.0 * s * (j * jnp.float32(2 * cnt - 4) - b) for cnt in range(5)
+            2.0 * s * (j * jnp.float32(nbr) - b) for nbr in _NBR
         ]
     return rows[-1.0], rows[1.0]
 
@@ -228,9 +249,9 @@ def _ising_sweep_body_packed(words, beta, parity, draw, *, j, b, rule):
     """`_ising_sweep_body` on bit-plane-packed spins (same protocol).
 
     ``words`` is the `_pack_spins` tuple; r is recovered from the beta
-    column.  The uniforms draw, acceptance comparison, and ΔE / acceptance
-    reductions reuse the exact unpacked expressions on restacked (r, l, l)
-    planes — only the spin storage and neighbour count differ.
+    column.  The uniforms draw and acceptance comparison reuse the exact
+    unpacked expressions on each replica's plane, and ΔE comes from the
+    same integer sums — only the spin storage and neighbour count differ.
     """
     r = beta.shape[0]
     neg_tab, pos_tab = _ising_de_tables(j, b)
@@ -240,8 +261,7 @@ def _ising_sweep_body_packed(words, beta, parity, draw, *, j, b, rule):
     for color in (0, 1):
         u = draw(color)
         new_words = []
-        de_planes = []
-        acc_planes = []
+        nbr_sums, s_sums, acc_sums = [], [], []
         for wi, word in enumerate(words):
             # 4-neighbour up-count via a bitwise full adder on rolled planes:
             # count bit-planes (n0, n1, n2) hold cnt = n0 + 2·n1 + 4·n2.
@@ -272,14 +292,21 @@ def _ising_sweep_body_packed(words, beta, parity, draw, *, j, b, rule):
                     parity == color
                 )
                 flips = flips | (accept.astype(jnp.uint32) << kk)
-                de_planes.append(de)
-                acc_planes.append(accept)
+                s_nbr = jnp.where(
+                    sbit > 0,
+                    _sel_cnt(b0, b1, b2, [float(n) for n in _NBR]),
+                    _sel_cnt(b0, b1, b2, [float(-n) for n in _NBR]),
+                )
+                nbr_sums.append(site_sum(jnp.where(accept, s_nbr, 0.0)[None]))
+                if b:
+                    s_i = jnp.where(sbit > 0, 1.0, -1.0)
+                    s_sums.append(site_sum(jnp.where(accept, s_i, 0.0)[None]))
+                acc_sums.append(site_sum(accept.astype(jnp.int32)[None]))
             new_words.append(word ^ flips)
         words = tuple(new_words)
-        de = jnp.stack(de_planes)
-        accept = jnp.stack(acc_planes)
-        ds = ds + site_sum(jnp.where(accept, de, 0.0))
-        na = na + site_sum(accept.astype(jnp.int32))
+        s_sum = jnp.concatenate(s_sums) if b else None
+        ds = ds + _flip_energy(jnp.concatenate(nbr_sums), s_sum, j, b)
+        na = na + jnp.concatenate(acc_sums)
     return words, ds, na
 
 

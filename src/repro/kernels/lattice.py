@@ -303,6 +303,7 @@ def _round_kernel(lat_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref,
     sk = prng.stream_key(kw)
     t0, ph0 = t0_ref[0, 0], ph0_ref[0, 0]
     betas = beta_ref[...]
+    betas_row = _kx.to_row(betas)  # once: each slot's lookup is then O(R)
     rung_out_ref[...] = rung_ref[...]
     energy_out_ref[...] = energy_ref[...]
     nacc_ref[...] = jnp.zeros((r, 1, 1), jnp.int32)
@@ -312,7 +313,7 @@ def _round_kernel(lat_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref,
         t_round = t0 + jnp.uint32(k * n_sweeps)
 
         def group(gi, rows, src=src, t_round=t_round):
-            beta = _kx.onehot_gather(betas, rung_out_ref[rows])
+            beta = _kx.gather_row(betas_row, rung_out_ref[rows])
             rep = prng.iota_u32((g, 1, 1), 0) + _u32(gi * g)
             out_ref[rows], de, na = _fused_sweeps(
                 kind, src[rows], beta, rep, sk, t_round, n_sweeps, par

@@ -31,7 +31,8 @@ widening to int32 — 4× denser working state, valid for ``q ≤ 64`` (the
 int8 intermediate ``s + d`` peaks at ``2q − 2``).  Comparisons and selects
 on int8 produce the same booleans, so the trajectory is bitwise identical
 (pinned by tests).  Mosaic on v5e has no int8 vector arithmetic, so this
-mode runs in interpret mode only.
+mode runs in interpret mode only; a compiled request is refused
+(`check_pack_bits`).
 
 VMEM working set per grid step ≈ r_blk · H · W · (2 int8 in/out + 4·4 u-f32 +
 2·4 i32 working copies + 4 de-f32) = 30·r_blk·H·W bytes — roughly 2.3× the
@@ -97,9 +98,24 @@ def _potts_sweep_body(s, beta, parity, draw, *, q, j, rule, packed):
     return s, ds, na
 
 
-def _kind(q, j, rule, pack_bits=False) -> Kind:
-    if pack_bits and q > 64:
+def check_pack_bits(q: int, *, interpret: bool) -> None:
+    """Refuse a ``pack_bits`` request the kernels cannot honour.
+
+    The int8 lanes hold ``s + d`` only for q ≤ 64, and Mosaic has no int8
+    vector arithmetic on a v5e, so the mode runs interpreted (CPU) only.
+    """
+    if q > 64:
         raise ValueError(f"pack_bits needs q <= 64 (int8 lanes), got q={q}")
+    if not interpret:
+        raise ValueError(
+            "Potts pack_bits computes in int8 vector lanes, which Mosaic "
+            "cannot compile for a TPU; it runs interpreted on the CPU only"
+        )
+
+
+def _kind(q, j, rule, pack_bits=False, interpret=True) -> Kind:
+    if pack_bits:
+        check_pack_bits(q, interpret=interpret)
     dtype = jnp.int8 if pack_bits else jnp.int32
     return Kind(
         load=lambda s8: s8.astype(dtype),
@@ -169,7 +185,7 @@ def potts_sweep_fused_pallas(
 
     Returns ``(states', delta_e, n_accepted)`` summed over the interval.
     """
-    kind = _kind(q, j, rule, pack_bits)
+    kind = _kind(q, j, rule, pack_bits, interpret)
     if replica_offset is None:
         replica_offset = jnp.zeros((1,), jnp.uint32)
     return fused_call(
@@ -204,7 +220,7 @@ def potts_round_fused_pallas(
     accept, prob, attempt)`` with diagnostics shaped (n_rounds, R)
     (accept/attempt as int32 0/1).
     """
-    kind = _kind(q, j, rule, pack_bits)
+    kind = _kind(q, j, rule, pack_bits, interpret)
     return round_call(
         kind, states, key_words, t0, phase0, rung, energy, betas,
         n_sweeps=n_sweeps, n_rounds=n_rounds, criterion=criterion,

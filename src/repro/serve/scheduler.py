@@ -115,7 +115,7 @@ class Scheduler:
         (chaos testing; None in production — zero-cost-off).
       strict_kernels: make a failed fused/Pallas compile fatal in every
         engine this scheduler builds instead of degrading to the per-sweep
-        path (``repro serve --strict-kernels``).
+        path.
 
     Use either synchronously (``submit(...)`` then ``run_until_idle()``) or
     as a service (``start()`` spawns the host loop thread; ``submit`` is

@@ -15,6 +15,7 @@ from repro.engine import Engine, EngineConfig
 from repro.engine.driver import StepSpec, make_interval_step
 from repro.exchange import make_strategy
 from repro.kernels import exchange as kx, ops, prng
+from repro.kernels.potts_sweep import potts_sweep_fused_pallas
 
 R, L = 6, 8
 TEMPS = np.asarray(ladder.linear_ladder(R, 1.0, 3.5))
@@ -195,11 +196,8 @@ def test_round_launch_split_invariance():
 @pytest.mark.parametrize("r,r_blk", [(3, 8), (6, 4), (8, 8), (33, 64)])
 def test_ising_packed_interval_bit_equal(r, r_blk):
     """pack_bits is storage only: bit-plane multispin updates must reproduce
-    the unpacked fused kernel's spins and acceptance counts bit-for-bit —
-    including pad > R tiles and a block wide enough (r_blk=64) to need a
-    second uint32 bit-plane word.  The packed kernel sums ΔE over the whole
-    block at once and the unpacked one replica by replica, so the f32 ΔE
-    totals agree to rounding."""
+    the unpacked fused kernel bit-for-bit — including pad > R tiles and a
+    block wide enough (r_blk=64) to need a second uint32 bit-plane word."""
     key = jax.random.key(60 + r)
     spins, betas = _rand_ising(key, r, L)
     kw = dict(n_sweeps=3, j=1.0, b=0.3, r_blk=r_blk, use_pallas=True)
@@ -207,11 +205,8 @@ def test_ising_packed_interval_bit_equal(r, r_blk):
     packed = ops.ising_sweep_fused(
         spins, key, jnp.int32(7), betas, pack_bits=True, **kw
     )
-    np.testing.assert_array_equal(np.asarray(packed[0]), np.asarray(plain[0]))
-    np.testing.assert_allclose(
-        np.asarray(packed[1]), np.asarray(plain[1]), rtol=1e-6, atol=1e-3
-    )
-    np.testing.assert_array_equal(np.asarray(packed[2]), np.asarray(plain[2]))
+    for a, b in zip(packed, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("r,r_blk,q", [(3, 8, 3), (5, 2, 5)])
@@ -240,6 +235,24 @@ def test_potts_pack_bits_rejects_large_q():
         )
     with pytest.raises(ValueError, match="q <= 64"):
         PottsSystem(shape=(4, 4), q=65, pack_bits=True)
+
+
+def test_potts_pack_bits_refused_where_compiled(monkeypatch):
+    """Mosaic cannot compile the int8 lanes: on a TPU the request is
+    refused, never left to degrade the run to the per-sweep path."""
+    states = jnp.zeros((4, 4, 4), jnp.int8)
+    with pytest.raises(ValueError, match="int8 vector lanes"):
+        potts_sweep_fused_pallas(
+            states, jnp.zeros((2,), jnp.uint32), jnp.zeros((1,), jnp.uint32),
+            jnp.ones((4,), jnp.float32), n_sweeps=1, q=3, pack_bits=True,
+            interpret=False,
+        )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="int8 vector lanes"):
+        PottsSystem(shape=(4, 4), use_pallas=True, use_fused=True,
+                    pack_bits=True)
+    # the reference path ignores the storage knob
+    PottsSystem(shape=(4, 4), use_fused=True, pack_bits=True)
 
 
 # ---------- engine integration: one launch per PT round -------------------------
