@@ -24,6 +24,8 @@ import time
 
 import jax
 
+from repro.obs.timeline import now as timeline_now
+
 # group -> list of record dicts, accumulated by `emit(..., group=...)`
 _RECORDS: dict[str, list] = {}
 
@@ -68,12 +70,13 @@ def time_call(fn, *args, warmup: int = 1, iters: int = 3, span: str | None = Non
     times = []
     name = span or getattr(fn, "__name__", "call")
     for _ in range(iters):
+        start = timeline_now()
         t0 = time.perf_counter()
         jax.block_until_ready(fn(*args))
         dt = time.perf_counter() - t0
         times.append(dt)
         if tl is not None:
-            tl.complete(name, t0, dt, cat="bench", track="bench")
+            tl.complete(name, start, dt, cat="bench", track="bench")
     times.sort()
     return times[len(times) // 2]
 

@@ -43,7 +43,7 @@ def _build(length: int, r: int, chunk_intervals: int, obs):
     config = EngineConfig(
         # chunks must carry real device work (64 sweeps here) for the
         # ratio to measure the contract rather than dispatch noise: the
-        # obs cost is per-chunk-constant (~100us: one sync + a few spans),
+        # obs cost is per-chunk-constant (two spans and a few counters),
         # so microscopic chunks would inflate the ratio meaninglessly
         n_replicas=r, swap_interval=8, chunk_intervals=chunk_intervals,
         # donation off so the same state object can be re-run for repeats
@@ -89,8 +89,9 @@ def measure(length: int = 32, r: int = 8, chunk_intervals: int = 8,
     n_chunks = float(obs.metrics.snapshot()
                      ["engine_chunks_total"]["samples"][0]["value"])
     # spans only — metadata/instant bookkeeping events are one-time, and
-    # span count per chunk is structural (device_wait + chunk per chunk,
-    # compile once), so the per-chunk rate is deterministic at fixed config
+    # span count per chunk is structural (repro.engine.chunk and
+    # repro.engine.dispatch per chunk, repro.engine.compile once), so the
+    # per-chunk rate is deterministic at fixed config
     n_spans = sum(1 for ev in obs.timeline.events() if ev["ph"] == "X")
     return {
         "wall_off": wall_off,

@@ -313,7 +313,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="write the run's metrics (Prometheus text format) "
                         "to this path")
     p.add_argument("--jax-profile", default=None, metavar="DIR",
-                   help="wrap one compiled chunk in jax.profiler and write "
+                   help="wrap the first engine run in jax.profiler and write "
                         "the device profile under DIR")
     p.add_argument("--strict-kernels", action="store_true",
                    help="fail loudly if a fused/Pallas mega-step compile "

@@ -27,7 +27,6 @@ import dataclasses
 import json
 import os
 import sys
-import time
 from typing import Any, Sequence
 
 import jax
@@ -37,6 +36,7 @@ from repro.api.spec import PhaseSpec, RunSpec
 from repro.checkpoint.manager import CheckpointManager
 from repro.engine import AdaptInfo, ChunkInfo, Engine, EngineState, RunResult
 from repro.engine.adapt import AdaptState
+from repro.obs.timeline import NULL
 
 __all__ = [
     "Callback",
@@ -157,11 +157,10 @@ class CheckpointCallback(Callback):
         adapt_st = session.engine._adapt_state
         if adapt_st is not None:
             meta.update(adapt_st.to_meta())
-        obs = getattr(session.engine, "obs", None)
-        if obs is not None:
-            with obs.timeline.span("checkpoint", cat="session", sweep=sweep):
-                self.manager.save(sweep, state, meta=meta)
-        else:
+        # one name with the engine's own checkpoint path: both save the
+        # engine state
+        with session.engine.timeline.span("repro.engine.checkpoint",
+                                          cat="session", sweep=sweep):
             self.manager.save(sweep, state, meta=meta)
         session.dispatch("on_checkpoint", sweep)
 
@@ -219,8 +218,8 @@ class ObsCallback(Callback):
 
     The composition point between the callback pipeline and the telemetry
     layer (DESIGN.md §Observability): on phase start the bundle is attached
-    to the Session's engine (arming the per-chunk spans and metrics inside
-    the host loop), phases land as spans on a ``session`` track, and after
+    to the Session's engine (so the engine's and the Session's spans land
+    in its timeline, and the host loop feeds its metrics), and after
     *every* phase the timeline/metrics files are (re)written atomically — a
     run that dies mid-schedule still leaves loadable artifacts on disk.
 
@@ -250,22 +249,12 @@ class ObsCallback(Callback):
         self.obs = obs
         self.timeline_path = timeline_path
         self.metrics_path = metrics_path
-        self._phase_t0: dict[str, float] = {}
 
     def on_phase_start(self, session, phase):
         if session.engine.obs is not self.obs:
             session.engine.obs = self.obs
-        self._phase_t0[phase.name] = time.perf_counter()
 
     def on_phase_end(self, session, phase, result):
-        t0 = self._phase_t0.pop(phase.name, None)
-        if t0 is not None:
-            self.obs.timeline.complete(
-                f"phase:{phase.name}", t0, time.perf_counter() - t0,
-                cat="session", track="session",
-                args={"n_sweeps": int(result.n_sweeps),
-                      "stopped_early": bool(result.stopped_early)},
-            )
         self.write()
 
     def write(self) -> dict:
@@ -369,23 +358,27 @@ class Session:
     ):
         self.spec = spec
         self.callbacks = list(callbacks)
-        self.system = spec.system.build()
-        self.temps = spec.ladder.build()
-        self.observables = spec.system.observables(self.system, spec.observables)
-        self._adapt = spec.adapt.build() if spec.adapt is not None else None
-        self.engine = Engine(
-            self.system,
-            spec.engine.build(
-                spec.ladder.n_replicas, exchange=spec.exchange.build()
-            ),
-            observables=self.observables,
-            # Engine.adapt is toggled per phase; constructing with it also
-            # validates it against the engine config (track_stats etc.).
-            adapt=self._adapt,
-            # a failed fused/Pallas compile normally degrades to the
-            # per-sweep path with a warning; --strict-kernels makes it fatal
-            strict_kernels=strict_kernels,
-        )
+        # no engine yet, so no timeline: the span reaches a profiler alone
+        with NULL.span("repro.session.build", cat="session"):
+            self.system = spec.system.build()
+            self.temps = spec.ladder.build()
+            self.observables = spec.system.observables(
+                self.system, spec.observables)
+            self._adapt = spec.adapt.build() if spec.adapt is not None else None
+            self.engine = Engine(
+                self.system,
+                spec.engine.build(
+                    spec.ladder.n_replicas, exchange=spec.exchange.build()
+                ),
+                observables=self.observables,
+                # Engine.adapt is toggled per phase; constructing with it
+                # also validates it against the engine config (track_stats).
+                adapt=self._adapt,
+                # a failed fused/Pallas compile normally degrades to the
+                # per-sweep path with a warning; --strict-kernels makes it
+                # fatal
+                strict_kernels=strict_kernels,
+            )
         self.state: EngineState | None = None
         self.current_phase: PhaseSpec | None = None
         self._restored_sweeps = 0
@@ -402,7 +395,8 @@ class Session:
     # -- state construction / resume -------------------------------------------
     def init_state(self) -> EngineState:
         """Fresh engine state exactly as the spec describes it."""
-        return self.engine.init(jax.random.key(self.spec.seed), self.temps)
+        with self.engine.timeline.span("repro.session.init", cat="session"):
+            return self.engine.init(jax.random.key(self.spec.seed), self.temps)
 
     @classmethod
     def from_checkpoint(
@@ -469,22 +463,28 @@ class Session:
             skip = 0
             self.current_phase = phase
             self.dispatch("on_phase_start", phase)
-            # Resuming mid-phase keeps the checkpointed accumulators: the
-            # reset already happened in the original run's phase start.
-            if phase.reset_stats and fresh_phase:
-                self.state = self.engine.reset_stats(self.state)
-            self.engine.adapt = self._adapt if phase.adapt else None
-            self.state, result = self.engine.run(
-                self.state,
-                budget,
-                on_chunk=lambda info: self.dispatch("on_chunk", info),
-                on_adapt=lambda info: self.dispatch("on_adapt", info),
-                # a trace-consuming callback owns the stream: don't also
-                # buffer every chunk for RunResult.trace
-                keep_trace=not any(
-                    getattr(cb, "consumes_trace", False) for cb in self.callbacks
-                ),
-            )
+            # after on_phase_start, which may attach obs to the engine
+            with self.engine.timeline.span("repro.session.phase",
+                                           cat="session", phase=phase.name) as sp:
+                # Resuming mid-phase keeps the checkpointed accumulators: the
+                # reset already happened in the original run's phase start.
+                if phase.reset_stats and fresh_phase:
+                    self.state = self.engine.reset_stats(self.state)
+                self.engine.adapt = self._adapt if phase.adapt else None
+                self.state, result = self.engine.run(
+                    self.state,
+                    budget,
+                    on_chunk=lambda info: self.dispatch("on_chunk", info),
+                    on_adapt=lambda info: self.dispatch("on_adapt", info),
+                    # a trace-consuming callback owns the stream: don't also
+                    # buffer every chunk for RunResult.trace
+                    keep_trace=not any(
+                        getattr(cb, "consumes_trace", False)
+                        for cb in self.callbacks
+                    ),
+                )
+                sp.annotate(n_sweeps=int(result.n_sweeps),
+                            stopped_early=bool(result.stopped_early))
             results[phase.name] = result
             self.dispatch("on_phase_end", phase, result)
             if result.stopped_early:

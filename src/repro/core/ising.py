@@ -189,7 +189,8 @@ class IsingSystem:
     # -- TPU-native mode ----------------------------------------------------
     def _checkerboard_sweep(self, key, spins, beta):
         """One full sweep = colour-0 then colour-1 half-sweeps (one replica)."""
-        u = jax.random.uniform(key, (2, self.length, self.length), jnp.float32)
+        with jax.named_scope("pt.rng"):
+            u = jax.random.uniform(key, (2, self.length, self.length), jnp.float32)
         from repro.kernels import ref as kref
 
         s, de, na = kref.ising_sweep(
@@ -207,7 +208,8 @@ class IsingSystem:
         if self.update == "single_flip":
             return jax.vmap(self._single_flip_steps)(keys, spins, betas)
         shape = (2, self.length, self.length)
-        u = jax.vmap(lambda k: jax.random.uniform(k, shape, jnp.float32))(keys)
+        with jax.named_scope("pt.rng"):
+            u = jax.vmap(lambda k: jax.random.uniform(k, shape, jnp.float32))(keys)
         from repro.kernels import ops as kops
 
         return kops.ising_sweep(

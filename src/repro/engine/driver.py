@@ -47,6 +47,7 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src import config as jax_config
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist_lib
@@ -58,6 +59,7 @@ from repro.engine.adapt import AdaptConfig, AdaptState, maybe_adapt
 from repro.exchange import DEO, ExchangeStrategy, make_strategy
 from repro.kernels import exchange as kernel_exchange
 from repro.kernels import prng as kernel_prng
+from repro.obs.timeline import NULL, gc_spans
 
 __all__ = [
     "StepSpec",
@@ -170,23 +172,26 @@ def _round_interval(system: System, spec: StepSpec):
 def _sweep_once(system, spec: StepSpec, betas, st: PTState, shard=None) -> PTState:
     """One parallel sweep of every replica at its current temperature."""
     r = spec.n_replicas
-    # 2t/2t+1 split keeps sweep and swap key streams disjoint for any R.
-    keys = jax.vmap(jax.random.fold_in, (None, 0))(
-        jax.random.fold_in(st.key, 2 * st.t), jnp.arange(r, dtype=jnp.uint32)
-    )
-    if shard is not None:
-        # pin the per-replica key axis: the per-replica random lattices then
-        # generate shard-local (otherwise the partitioner replicates the
-        # whole PRNG stream — measured 16x redundant HBM traffic)
-        keys = jax.lax.with_sharding_constraint(keys, shard)
-    betas_slot = betas[st.rung]
-    states, de, _ = _batched_step(system)(keys, st.states, betas_slot)
-    return dataclasses.replace(
-        st,
-        states=states,
-        energy=st.energy + de.astype(jnp.float32),
-        t=st.t + 1,
-    )
+    with jax.named_scope("pt.sweep"):
+        with jax.named_scope("pt.rng"):
+            # 2t/2t+1 split keeps sweep and swap key streams disjoint for any R.
+            keys = jax.vmap(jax.random.fold_in, (None, 0))(
+                jax.random.fold_in(st.key, 2 * st.t),
+                jnp.arange(r, dtype=jnp.uint32),
+            )
+        if shard is not None:
+            # pin the per-replica key axis: the per-replica random lattices
+            # then generate shard-local (otherwise the partitioner replicates
+            # the whole PRNG stream — measured 16x redundant HBM traffic)
+            keys = jax.lax.with_sharding_constraint(keys, shard)
+        betas_slot = betas[st.rung]
+        states, de, _ = _batched_step(system)(keys, st.states, betas_slot)
+        return dataclasses.replace(
+            st,
+            states=states,
+            energy=st.energy + de.astype(jnp.float32),
+            t=st.t + 1,
+        )
 
 
 def _swap_decision(spec: StepSpec, betas, st: PTState):
@@ -196,18 +201,19 @@ def _swap_decision(spec: StepSpec, betas, st: PTState):
     pairing involution in rung space, ``perm`` the accepted rung permutation.
     """
     r = spec.n_replicas
-    k_swap = jax.random.fold_in(st.key, 2 * st.t + 1)
-    inv = jnp.argsort(st.rung)  # slot holding rung r
-    e_rung = st.energy[inv]
-    strat = spec.exchange
-    partner = strat.propose_pairs(k_swap, st.phase, r)
-    # Attempts are the structural pairing mask, NOT `prob > 0`: a badly
-    # spaced pair can underflow sigmoid to exactly 0 in f32 and would
-    # otherwise never register an attempt — starving the adaptive-ladder
-    # feedback in precisely the case it exists to fix.
-    perm, accept, prob, attempt = strat.accept(
-        k_swap, partner, betas, e_rung, criterion=spec.criterion
-    )
+    with jax.named_scope("pt.exchange"):
+        k_swap = jax.random.fold_in(st.key, 2 * st.t + 1)
+        inv = jnp.argsort(st.rung)  # slot holding rung r
+        e_rung = st.energy[inv]
+        strat = spec.exchange
+        partner = strat.propose_pairs(k_swap, st.phase, r)
+        # Attempts are the structural pairing mask, NOT `prob > 0`: a badly
+        # spaced pair can underflow sigmoid to exactly 0 in f32 and would
+        # otherwise never register an attempt — starving the adaptive-ladder
+        # feedback in precisely the case it exists to fix.
+        perm, accept, prob, attempt = strat.accept(
+            k_swap, partner, betas, e_rung, criterion=spec.criterion
+        )
     diag = {"swap_accept": accept, "swap_prob": prob, "swap_attempt": attempt}
     return partner, perm, diag
 
@@ -215,16 +221,18 @@ def _swap_decision(spec: StepSpec, betas, st: PTState):
 def _apply_swap(spec: StepSpec, st: PTState, perm) -> PTState:
     """Apply an accepted rung permutation and advance the phase counter."""
     r = spec.n_replicas
-    if spec.swap_mode == "temp":
-        # Slot inv[r] now holds rung perm[r]; states stay in place.
-        inv = jnp.argsort(st.rung)
-        new_rung = jnp.zeros((r,), jnp.int32).at[inv].set(perm)
-        st = dataclasses.replace(st, rung=new_rung)
-    else:
-        # Faithful mode: rung == slot identity; move the states themselves.
-        states = jax.tree_util.tree_map(lambda x: jnp.take(x, perm, axis=0), st.states)
-        st = dataclasses.replace(st, states=states, energy=st.energy[perm])
-    return dataclasses.replace(st, phase=st.phase + 1)
+    with jax.named_scope("pt.exchange"):
+        if spec.swap_mode == "temp":
+            # Slot inv[r] now holds rung perm[r]; states stay in place.
+            inv = jnp.argsort(st.rung)
+            new_rung = jnp.zeros((r,), jnp.int32).at[inv].set(perm)
+            st = dataclasses.replace(st, rung=new_rung)
+        else:
+            # Faithful mode: rung == slot identity; move the states themselves.
+            states = jax.tree_util.tree_map(
+                lambda x: jnp.take(x, perm, axis=0), st.states)
+            st = dataclasses.replace(st, states=states, energy=st.energy[perm])
+        return dataclasses.replace(st, phase=st.phase + 1)
 
 
 def _swap_phase(spec: StepSpec, betas, st: PTState):
@@ -235,11 +243,12 @@ def _swap_phase(spec: StepSpec, betas, st: PTState):
 
 def _observe(system, observables, st: PTState) -> Mapping[str, jax.Array]:
     """Per-rung diagnostics (rung order, cold->hot)."""
-    inv = jnp.argsort(st.rung)
-    out = {"energy": st.energy[inv]}
-    for name, fn in (observables or {}).items():
-        vals = jax.vmap(fn)(st.states)
-        out[name] = vals[inv]
+    with jax.named_scope("pt.observe"):
+        inv = jnp.argsort(st.rung)
+        out = {"energy": st.energy[inv]}
+        for name, fn in (observables or {}).items():
+            vals = jax.vmap(fn)(st.states)
+            out[name] = vals[inv]
     return out
 
 
@@ -280,11 +289,12 @@ def make_interval_step(
             # loop AND the temp-mode exchange (swap uniforms from the counter
             # PRNG's swap stream, keyed on st.phase), so nothing but the
             # post-round state crosses the launch boundary.
-            states, rung, energy, _, acc, prob, att = fused_round(
-                st.key, st.t, st.phase, st.states, st.rung, st.energy,
-                betas, n_sweeps=spec.sweeps_per_interval,
-                criterion=spec.criterion, pairing=spec.exchange.name,
-            )
+            with jax.named_scope("pt.sweep"):
+                states, rung, energy, _, acc, prob, att = fused_round(
+                    st.key, st.t, st.phase, st.states, st.rung, st.energy,
+                    betas, n_sweeps=spec.sweeps_per_interval,
+                    criterion=spec.criterion, pairing=spec.exchange.name,
+                )
             st = constrain(dataclasses.replace(
                 st,
                 states=states,
@@ -307,16 +317,17 @@ def make_interval_step(
             # loop (VMEM-resident states, in-kernel counter PRNG keyed on the
             # same (st.key, st.t) the per-sweep path derives from); the
             # driver just advances the incremental energy and the counter.
-            states, de, _ = fused(
-                st.key, st.t, st.states, betas[st.rung],
-                n_sweeps=spec.sweeps_per_interval,
-            )
-            st = constrain(dataclasses.replace(
-                st,
-                states=states,
-                energy=st.energy + de.astype(jnp.float32),
-                t=st.t + spec.sweeps_per_interval,
-            ))
+            with jax.named_scope("pt.sweep"):
+                states, de, _ = fused(
+                    st.key, st.t, st.states, betas[st.rung],
+                    n_sweeps=spec.sweeps_per_interval,
+                )
+                st = constrain(dataclasses.replace(
+                    st,
+                    states=states,
+                    energy=st.energy + de.astype(jnp.float32),
+                    t=st.t + spec.sweeps_per_interval,
+                ))
         else:
             def sweep_body(s, _):
                 return constrain(_sweep_once(system, spec, betas, s, shard)), None
@@ -364,13 +375,14 @@ def _observe_full(observables, st_local: PTState, full: PTState):
     observables are evaluated on the *local* lattice block and all-gathered
     as O(R) scalar rows — lattices never cross devices.
     """
-    inv = jnp.argsort(full.rung)
-    out = {"energy": full.energy[inv]}
-    for name, fn in (observables or {}).items():
-        vals = jax.lax.all_gather(
-            jax.vmap(fn)(st_local.states), REPLICA_AXIS, tiled=True
-        )
-        out[name] = vals[inv]
+    with jax.named_scope("pt.observe"):
+        inv = jnp.argsort(full.rung)
+        out = {"energy": full.energy[inv]}
+        for name, fn in (observables or {}).items():
+            vals = jax.lax.all_gather(
+                jax.vmap(fn)(st_local.states), REPLICA_AXIS, tiled=True
+            )
+            out[name] = vals[inv]
     return out
 
 
@@ -410,38 +422,43 @@ def make_sharded_interval_step(
     r = spec.n_replicas
 
     def gather(x):
-        return jax.lax.all_gather(x, REPLICA_AXIS, tiled=True)
+        with jax.named_scope("pt.exchange"):
+            return jax.lax.all_gather(x, REPLICA_AXIS, tiled=True)
 
     def step(st: PTState, betas):
         r_local = st.energy.shape[0]
         start = jax.lax.axis_index(REPLICA_AXIS) * r_local
         offset = start.astype(jnp.uint32)
         if fused is not None:
-            states, de, _ = fused(
-                st.key, st.t, st.states, betas[st.rung],
-                n_sweeps=spec.sweeps_per_interval, replica_offset=offset,
-            )
-            st = dataclasses.replace(
-                st,
-                states=states,
-                energy=st.energy + de.astype(jnp.float32),
-                t=st.t + spec.sweeps_per_interval,
-            )
+            with jax.named_scope("pt.sweep"):
+                states, de, _ = fused(
+                    st.key, st.t, st.states, betas[st.rung],
+                    n_sweeps=spec.sweeps_per_interval, replica_offset=offset,
+                )
+                st = dataclasses.replace(
+                    st,
+                    states=states,
+                    energy=st.energy + de.astype(jnp.float32),
+                    t=st.t + spec.sweeps_per_interval,
+                )
         else:
             def sweep_body(s, _):
-                # global slot ids into fold_in: slot k's stream is invariant
-                # to how the replica axis is carved up
-                keys = jax.vmap(jax.random.fold_in, (None, 0))(
-                    jax.random.fold_in(s.key, 2 * s.t),
-                    offset + jnp.arange(r_local, dtype=jnp.uint32),
-                )
-                states, de, _ = _batched_step(system)(keys, s.states, betas[s.rung])
-                return dataclasses.replace(
-                    s,
-                    states=states,
-                    energy=s.energy + de.astype(jnp.float32),
-                    t=s.t + 1,
-                ), None
+                with jax.named_scope("pt.sweep"):
+                    with jax.named_scope("pt.rng"):
+                        # global slot ids into fold_in: slot k's stream is
+                        # invariant to how the replica axis is carved up
+                        keys = jax.vmap(jax.random.fold_in, (None, 0))(
+                            jax.random.fold_in(s.key, 2 * s.t),
+                            offset + jnp.arange(r_local, dtype=jnp.uint32),
+                        )
+                    states, de, _ = _batched_step(system)(
+                        keys, s.states, betas[s.rung])
+                    return dataclasses.replace(
+                        s,
+                        states=states,
+                        energy=s.energy + de.astype(jnp.float32),
+                        t=s.t + 1,
+                    ), None
 
             st, _ = jax.lax.scan(
                 sweep_body, st, None, length=spec.sweeps_per_interval
@@ -453,9 +470,10 @@ def make_sharded_interval_step(
         )
 
         def pull_back(local: PTState, full_after: PTState) -> PTState:
-            new_rung = jax.lax.dynamic_slice_in_dim(
-                full_after.rung, start, r_local
-            )
+            with jax.named_scope("pt.exchange"):
+                new_rung = jax.lax.dynamic_slice_in_dim(
+                    full_after.rung, start, r_local
+                )
             local = dataclasses.replace(
                 local, rung=new_rung, phase=full_after.phase
             )
@@ -475,11 +493,12 @@ def make_sharded_interval_step(
             # stream the round kernel uses (`repro.kernels.exchange`), which
             # keeps a sharded ``use_fused_round`` run bit-equal to the
             # single-device whole-round launch at identical seeds.
-            new_rung, acc, prob, att, _ = kernel_exchange.exchange_step(
-                full.rung, full.energy, betas, st.phase,
-                kernel_prng.key_words(st.key),
-                pairing=spec.exchange.name, criterion=spec.criterion,
-            )
+            with jax.named_scope("pt.exchange"):
+                new_rung, acc, prob, att, _ = kernel_exchange.exchange_step(
+                    full.rung, full.energy, betas, st.phase,
+                    kernel_prng.key_words(st.key),
+                    pairing=spec.exchange.name, criterion=spec.criterion,
+                )
             full = dataclasses.replace(
                 full, rung=new_rung, phase=full.phase + 1
             )
@@ -688,29 +707,28 @@ class AdaptInfo:
 
 
 class _EngineObs:
-    """Pre-resolved metric handles + timeline for an instrumented engine.
+    """Pre-resolved metric handles for an instrumented engine.
 
     Built once when an `repro.obs.Observability` is attached (``engine.obs =
-    obs``); never constructed on the obs-off path, which is the structural
-    zero-overhead contract: with ``obs=None`` the host loop performs exactly
-    one ``is None`` test per site and allocates nothing.
+    obs``); never constructed on the obs-off path, where the host loop makes
+    one ``is None`` test per metric site and allocates nothing.  The spans
+    do not live here: the engine always holds a timeline (`Engine.timeline`,
+    ``NULL`` with obs off), whose spans reach a running profiler either way.
 
-    All series here derive from state the engine already holds on host —
-    the O(R) pooled swap/flow counters, wall-clock timestamps, compile
-    bookkeeping.  Nothing in this class touches device buffers beyond the
-    `block_until_ready` the chunk span needs for an honest duration.
+    Every series derives from what the host loop already has: the host
+    clock, compile bookkeeping, and the O(R) pooled counters at the points
+    where the host reads them anyway (a retune check, the end of a run).
+    Nothing here waits for the device.
     """
 
     __slots__ = (
-        "obs", "timeline", "compiles", "compile_seconds", "chunks", "sweeps",
-        "chunk_seconds", "device_seconds", "host_seconds", "sweeps_per_sec",
-        "swap_acc", "flow_up", "adapt_rounds", "checkpoints", "hbm_bytes",
+        "obs", "compiles", "compile_seconds", "chunks", "sweeps",
+        "chunk_seconds", "swap_acc", "flow_up", "adapt_rounds", "checkpoints",
         "degraded_kernel", "_last_counters",
     )
 
-    def __init__(self, obs, system, config):
+    def __init__(self, obs, config):
         self.obs = obs
-        self.timeline = obs.timeline
         m = obs.metrics
         self.compiles = m.counter(
             "engine_compiles_total", "mega-step AOT compiles")
@@ -721,16 +739,10 @@ class _EngineObs:
         self.sweeps = m.counter(
             "engine_sweeps_total", "sweeps advanced (per chain)")
         self.chunk_seconds = m.histogram(
-            "engine_chunk_seconds", "wall time per compiled chunk")
-        self.device_seconds = m.counter(
-            "engine_device_seconds_total",
-            "wall seconds waiting on device inside chunks")
-        self.host_seconds = m.counter(
-            "engine_host_seconds_total",
-            "host-side overhead between device launches (adapt, trace drain, "
-            "checkpoint, callbacks)")
-        self.sweeps_per_sec = m.gauge(
-            "engine_sweeps_per_sec", "throughput of the last chunk")
+            "engine_chunk_seconds",
+            "host seconds between successive chunk dispatch returns (the "
+            "host loop's period; the device's time per chunk once the host "
+            "runs ahead)")
         self.adapt_rounds = m.counter(
             "engine_adapt_rounds_total", "ladder retunes performed")
         self.checkpoints = m.counter(
@@ -749,48 +761,10 @@ class _EngineObs:
         # window deltas for the acceptance gauges: cumulative counters would
         # smear early-run transients over the whole series
         self._last_counters = None
-        self.hbm_bytes = self._modeled_hbm_bytes(system, config)
-
-    @staticmethod
-    def _modeled_hbm_bytes(system, config) -> float | None:
-        """Modeled HBM bytes per chunk launch (analytic sweep-kernel model).
-
-        Best-effort: only lattice systems exposing ``length`` participate;
-        anything else annotates nothing rather than a wrong number.
-        """
-        L = getattr(system, "length", None)
-        if L is None:
-            return None
-        from repro.hlo.traffic import hbm_bytes_per_cell_sweep
-
-        spi = config.spec.sweeps_per_interval
-        per_cell = hbm_bytes_per_cell_sweep(
-            fused=getattr(system, "use_fused", False),
-            sweeps_per_interval=spi,
-            rounds_per_launch=(
-                config.chunk_intervals
-                if getattr(system, "use_fused_round", False) else 1
-            ),
-            # Potts moves two random planes per sweep (proposal + accept)
-            uniform_plane_bytes=16.0 if hasattr(system, "q") else 8.0,
-        )
-        cells = float(L) * float(L)
-        sweeps = spi * config.chunk_intervals
-        return per_cell * cells * sweeps * config.n_replicas * config.n_chains
-
-    def record_chunk(self, state, *, intervals, spi, device_s, wall_s) -> None:
-        """Per-chunk series: throughput, durations, live rung diagnostics."""
-        sweeps = intervals * spi
-        self.chunks.inc()
-        self.sweeps.inc(sweeps)
-        self.chunk_seconds.observe(wall_s)
-        self.device_seconds.inc(device_s)
-        self.host_seconds.inc(max(wall_s - device_s, 0.0))
-        if wall_s > 0:
-            self.sweeps_per_sec.set(sweeps / wall_s)
 
     def record_rungs(self, counters: dict[str, np.ndarray]) -> None:
-        """Refresh the per-rung gauges from this chunk's counter deltas."""
+        """Refresh the per-rung gauges from the counter deltas since the
+        last refresh."""
         last = self._last_counters
         self._last_counters = counters
         if last is not None:
@@ -872,9 +846,11 @@ class Engine:
         # (restored from checkpoint meta on resume)
         self._temps: np.ndarray | None = None
         # observability handle (repro.obs.Observability) — None keeps every
-        # instrumentation site down to a single `is None` test (the
-        # zero-overhead-off contract pinned by tests/test_obs.py)
+        # metric site down to a single `is None` test (the zero-overhead-off
+        # contract pinned by tests/test_obs.py).  The timeline is always
+        # there: NULL with obs off, whose spans reach only a running profiler
         self._eobs: _EngineObs | None = None
+        self.timeline = NULL
         if obs is not None:
             self.obs = obs
         # fault-injection handle (repro.resilience.FaultPlan) — same
@@ -899,8 +875,9 @@ class Engine:
         # is attribute access + float ops — no name lookups per chunk
         self._eobs = (
             None if value is None
-            else _EngineObs(value, self.system, self.config)
+            else _EngineObs(value, self.config)
         )
+        self.timeline = NULL if value is None else value.timeline
 
     # -- state construction ----------------------------------------------------
     def _init_single(self, key: jax.Array) -> PTState:
@@ -1031,7 +1008,8 @@ class Engine:
                 pt_st, stats = carry
                 pt_st, rec = step(pt_st, betas)
                 if cfg.track_stats:
-                    stats = stats_lib.update_stats(stats, rec, pt_st.rung)
+                    with jax.named_scope("pt.stats"):
+                        stats = stats_lib.update_stats(stats, rec, pt_st.rung)
                 return (pt_st, stats), (rec if cfg.record_trace else None)
 
             (pt_st, stats), trace = jax.lax.scan(
@@ -1062,7 +1040,8 @@ class Engine:
                 pt_st, stats = carry
                 pt_st, rec, rung_full = step(pt_st, betas)
                 if cfg.track_stats:
-                    stats = stats_lib.update_stats(stats, rec, rung_full)
+                    with jax.named_scope("pt.stats"):
+                        stats = stats_lib.update_stats(stats, rec, rung_full)
                 return (pt_st, stats), (rec if cfg.record_trace else None)
 
             (pt_st, stats), trace = jax.lax.scan(
@@ -1097,7 +1076,6 @@ class Engine:
         exe = self._executables.get(chunk_len)
         if exe is None:
             eo = self._eobs
-            t0 = time.perf_counter() if eo is not None else 0.0
             sds = lambda tree: jax.tree_util.tree_map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=getattr(x, "sharding", None)), tree
             )
@@ -1107,26 +1085,34 @@ class Engine:
             # are configuration mistakes and must stay loud, never silently
             # degraded
             jitted = jax.jit(self._make_mega(chunk_len, state), donate_argnums=donate)
-            try:
-                if self._faults is not None:
-                    self._faults.fire("engine.compile")
-                exe = jitted.lower(
-                    sds(state.pt), sds(state.stats), sds(state.betas)
-                ).compile()
-            except Exception as err:
-                return self._degrade(err, state, chunk_len)
+            with self.timeline.span(
+                "repro.engine.compile", cat="compile",
+                chunk_intervals=chunk_len, n_replicas=self.config.n_replicas,
+                n_chains=self.config.n_chains,
+            ):
+                t0 = time.perf_counter()
+                try:
+                    if self._faults is not None:
+                        self._faults.fire("engine.compile")
+                    lowered = jitted.lower(
+                        sds(state.pt), sds(state.stats), sds(state.betas)
+                    )
+                    # The pt.* scopes live in the program's debug info,
+                    # which JAX's persistent cache key leaves out: a cached
+                    # executable built from the same program without them
+                    # (or with others) would be loaded in its place, and
+                    # its ops would carry that program's names.  Keyed with
+                    # the metadata, an executable always has its own scopes.
+                    with jax_config.compilation_cache_include_metadata_in_key(True):
+                        exe = lowered.compile()
+                except Exception as err:
+                    # the per-sweep recompile nests its own compile span
+                    return self._degrade(err, state, chunk_len)
             self._executables[chunk_len] = exe
             self.n_compiles += 1
             if eo is not None:
-                dt = time.perf_counter() - t0
                 eo.compiles.inc()
-                eo.compile_seconds.inc(dt)
-                eo.timeline.complete(
-                    "compile", t0, dt, cat="compile",
-                    args={"chunk_intervals": chunk_len,
-                          "n_replicas": self.config.n_replicas,
-                          "n_chains": self.config.n_chains},
-                )
+                eo.compile_seconds.inc(time.perf_counter() - t0)
         return exe
 
     def _degrade(self, err: Exception, state: EngineState, chunk_len: int):
@@ -1242,162 +1228,129 @@ class Engine:
         chunk_idx = 0
         stopped = False
         eo = self._eobs
-        while done < n_intervals:
-            this = min(self.config.chunk_intervals, n_intervals - done)
-            if self._faults is not None:
-                f = self._faults.check("engine.chunk.stall")
-                if f is not None:
-                    time.sleep(f.duration)
-                self._faults.fire("engine.chunk.launch")
-            if eo is not None:
-                # instrumented launch: same executable, plus wall/device
-                # timing and the one-shot jax.profiler window if armed.  The
-                # block_until_ready makes the device-wait span honest; its
-                # cost is covered by the <5% obs-on budget and never paid
-                # when obs is off.
-                t_chunk0 = time.perf_counter()
-                exe = self._compiled(state, this)
-                profiling = eo.obs.start_jax_profile()
-                t_launch = time.perf_counter()
-                pt_st, stats, trace = exe(state.pt, state.stats, state.betas)
-                jax.block_until_ready(pt_st)
-                device_s = time.perf_counter() - t_launch
-                if profiling:
-                    eo.obs.stop_jax_profile()
-            else:
-                pt_st, stats, trace = self._compiled(state, this)(
-                    state.pt, state.stats, state.betas
-                )
-            state = EngineState(pt=pt_st, stats=stats, betas=state.betas)
-            if self._faults is not None:
-                f = self._faults.check("engine.energy.nonfinite")
-                if f is not None:
-                    # a failing device lane: poison one chain's energies on
-                    # host (chains are independent — NaN never crosses the
-                    # ensemble axis, so only the owning tenant is affected)
-                    e = np.asarray(state.pt.energy).copy()
-                    if e.ndim == 2:
-                        e[f.chain % e.shape[0]] = np.nan
-                    else:
-                        e[:] = np.nan
-                    state = self.place(dataclasses.replace(
-                        state,
-                        pt=dataclasses.replace(
-                            state.pt, energy=jnp.asarray(e, state.pt.energy.dtype)
-                        ),
-                    ))
-            done += this
-            chunk_idx += 1
-            if eo is not None:
-                eo.timeline.complete(
-                    "device_wait", t_launch, device_s, cat="engine",
-                    args={"chunk": chunk_idx, "intervals": this},
-                )
-            chunk_np = None
-            if self.config.record_trace:
-                if eo is not None:
-                    with eo.timeline.span("trace_drain", chunk=chunk_idx):
-                        chunk_np = {k: np.asarray(v) for k, v in trace.items()}
-                else:
-                    chunk_np = {k: np.asarray(v) for k, v in trace.items()}
-                if keep_trace:
-                    chunks.append(chunk_np)
-            if self.adapt is not None and done < n_intervals:
-                t_adapt0 = time.perf_counter() if eo is not None else 0.0
-                new_temps, acceptance = maybe_adapt(
-                    temps, self._pooled_counters(state), self.adapt, adapt_st
-                )
-                if new_temps is not None:
-                    temps = np.asarray(new_temps, np.float64)
-                    self._temps = temps
-                    ladder_history.append(temps.astype(np.float32))
-                    self._adapt_rounds = adapt_st.rounds
-                    # Restart the moment accumulators: per-rung means/vars
-                    # must not pool samples drawn at two different ladders
-                    # (swap counters stay — the adapt window is baselined,
-                    # and flow/round-trip labels are chain state).  The
-                    # weight totals are part of the moment state — a stale
-                    # weight_sum would deflate post-retune variances and
-                    # freeze the weighted (VMPT) mean updates.
-                    zeros = lambda tree: jax.tree_util.tree_map(
-                        jnp.zeros_like, tree
-                    )
-                    stats = dataclasses.replace(
-                        state.stats,
-                        n_records=zeros(state.stats.n_records),
-                        weight_sum=zeros(state.stats.weight_sum),
-                        mean=zeros(state.stats.mean),
-                        m2=zeros(state.stats.m2),
-                    )
-                    state = self.place(dataclasses.replace(
-                        state,
-                        stats=stats,
-                        betas=jnp.asarray(1.0 / temps, jnp.float32),
-                    ))
-                    if on_adapt is not None:
-                        on_adapt(AdaptInfo(
-                            round=adapt_st.rounds,
-                            temps=temps.astype(np.float32).copy(),
-                            acceptance=np.asarray(acceptance, np.float64),
+        tl = self.timeline
+        # the one-shot jax.profiler window, if armed, covers this whole run:
+        # it closes once the summary below has read the last chunk's stats
+        profiling = eo is not None and eo.obs.start_jax_profile()
+        t_dispatched = None  # host clock at the previous dispatch return
+        with gc_spans:
+            while done < n_intervals:
+                this = min(self.config.chunk_intervals, n_intervals - done)
+                chunk_idx += 1
+                with tl.span("repro.engine.chunk", chunk=chunk_idx,
+                             intervals=this):
+                    if self._faults is not None:
+                        f = self._faults.check("engine.chunk.stall")
+                        if f is not None:
+                            time.sleep(f.duration)
+                        self._faults.fire("engine.chunk.launch")
+                    exe = self._compiled(state, this)
+                    # no wait for the device here, obs on or off: the host
+                    # runs ahead and the trace shows where the device idles
+                    with tl.span("repro.engine.dispatch"):
+                        pt_st, stats, trace = exe(
+                            state.pt, state.stats, state.betas
+                        )
+                    if eo is not None:
+                        t = time.perf_counter()
+                        if t_dispatched is not None:
+                            eo.chunk_seconds.observe(t - t_dispatched)
+                        t_dispatched = t
+                    state = EngineState(pt=pt_st, stats=stats, betas=state.betas)
+                    if self._faults is not None:
+                        f = self._faults.check("engine.energy.nonfinite")
+                        if f is not None:
+                            # a failing device lane: poison one chain's
+                            # energies on host (chains are independent — NaN
+                            # never crosses the ensemble axis, so only the
+                            # owning tenant is affected)
+                            e = np.asarray(state.pt.energy).copy()
+                            if e.ndim == 2:
+                                e[f.chain % e.shape[0]] = np.nan
+                            else:
+                                e[:] = np.nan
+                            state = self.place(dataclasses.replace(
+                                state,
+                                pt=dataclasses.replace(
+                                    state.pt,
+                                    energy=jnp.asarray(e, state.pt.energy.dtype),
+                                ),
+                            ))
+                    done += this
+                    if eo is not None:
+                        eo.chunks.inc()
+                        eo.sweeps.inc(this * spi)
+                    chunk_np = None
+                    if self.config.record_trace:
+                        with tl.span("repro.engine.trace_drain"):
+                            chunk_np = {k: np.asarray(v) for k, v in trace.items()}
+                        if keep_trace:
+                            chunks.append(chunk_np)
+                    if self.adapt is not None and done < n_intervals:
+                        with tl.span("repro.engine.adapt") as sp:
+                            counters = self._pooled_counters(state)
+                            if eo is not None and self.config.track_stats:
+                                eo.record_rungs(counters)
+                            new_temps, acceptance = maybe_adapt(
+                                temps, counters, self.adapt, adapt_st
+                            )
+                            sp.annotate(retuned=new_temps is not None,
+                                        round=adapt_st.rounds)
+                            if new_temps is not None:
+                                temps = np.asarray(new_temps, np.float64)
+                                self._temps = temps
+                                ladder_history.append(temps.astype(np.float32))
+                                self._adapt_rounds = adapt_st.rounds
+                                state = self._retuned(state, temps)
+                                if eo is not None:
+                                    eo.adapt_rounds.inc()
+                                if on_adapt is not None:
+                                    on_adapt(AdaptInfo(
+                                        round=adapt_st.rounds,
+                                        temps=temps.astype(np.float32).copy(),
+                                        acceptance=np.asarray(acceptance, np.float64),
+                                        sweeps_done=done * spi,
+                                    ))
+                    if (
+                        checkpoint is not None
+                        and checkpoint_every_chunks > 0
+                        and (chunk_idx % checkpoint_every_chunks == 0
+                             or done == n_intervals)
+                    ):
+                        sweep = int(np.asarray(pt_st.t).reshape(-1)[0])
+                        # same meta contract as repro.api.CheckpointCallback:
+                        # the exact f64 ladder plus the adaptation
+                        # bookkeeping, so either checkpoint path resumes
+                        # bit-equal
+                        meta = {
+                            "temps": [float(t) for t in temps],
+                            "adapt_rounds": self._adapt_rounds,
+                        }
+                        if self._adapt_state is not None:
+                            meta.update(self._adapt_state.to_meta())
+                        with tl.span("repro.engine.checkpoint", sweep=sweep):
+                            checkpoint.save(sweep, state, meta=meta)
+                        if eo is not None:
+                            eo.checkpoints.inc()
+                    if on_chunk is not None:
+                        info = ChunkInfo(
+                            index=chunk_idx,
                             sweeps_done=done * spi,
-                        ))
-                if eo is not None:
-                    eo.timeline.complete(
-                        "adapt", t_adapt0, time.perf_counter() - t_adapt0,
-                        cat="engine",
-                        args={"retuned": new_temps is not None,
-                              "round": adapt_st.rounds},
-                    )
-                    if new_temps is not None:
-                        eo.adapt_rounds.inc()
-            if (
-                checkpoint is not None
-                and checkpoint_every_chunks > 0
-                and (chunk_idx % checkpoint_every_chunks == 0 or done == n_intervals)
-            ):
-                sweep = int(np.asarray(pt_st.t).reshape(-1)[0])
-                # same meta contract as repro.api.CheckpointCallback: the
-                # exact f64 ladder plus the adaptation bookkeeping, so
-                # either checkpoint path resumes bit-equal
-                meta = {
-                    "temps": [float(t) for t in temps],
-                    "adapt_rounds": self._adapt_rounds,
-                }
-                if self._adapt_state is not None:
-                    meta.update(self._adapt_state.to_meta())
-                if eo is not None:
-                    with eo.timeline.span("checkpoint", sweep=sweep):
-                        checkpoint.save(sweep, state, meta=meta)
-                    eo.checkpoints.inc()
-                else:
-                    checkpoint.save(sweep, state, meta=meta)
-            if eo is not None:
-                wall = time.perf_counter() - t_chunk0
-                args = {"chunk": chunk_idx, "intervals": this,
-                        "sweeps_done": done * spi}
-                if eo.hbm_bytes is not None:
-                    args["modeled_hbm_bytes"] = (
-                        eo.hbm_bytes * this / self.config.chunk_intervals
-                    )
-                eo.timeline.complete("chunk", t_chunk0, wall,
-                                     cat="engine", args=args)
-                eo.record_chunk(state, intervals=this, spi=spi,
-                                device_s=device_s, wall_s=wall)
-                if self.config.track_stats:
-                    eo.record_rungs(self._pooled_counters(state))
-            if on_chunk is not None:
-                info = ChunkInfo(
-                    index=chunk_idx,
-                    sweeps_done=done * spi,
-                    n_sweeps=n_sweeps,
-                    state=state,
-                    trace=chunk_np,
-                )
-                if on_chunk(info):
-                    # a stop request on the final chunk still counts: the
-                    # caller (e.g. Session) must see it to skip later phases
-                    stopped = True
-                    break
+                            n_sweeps=n_sweeps,
+                            state=state,
+                            trace=chunk_np,
+                        )
+                        with tl.span("repro.engine.callbacks"):
+                            stop = on_chunk(info)
+                        if stop:
+                            # a stop request on the final chunk still
+                            # counts: the caller (e.g. Session) must see it
+                            # to skip later phases
+                            stopped = True
+                            break
+            # reads the last chunk's stats back: the host waits for the device
+            with tl.span("repro.engine.summary"):
+                summary = stats_lib.summarize(state.stats)
 
         trace_out = None
         if chunks:
@@ -1407,13 +1360,43 @@ class Engine:
                 for k in chunks[0]
             }
         result = RunResult(
-            summary=stats_lib.summarize(state.stats),
+            summary=summary,
             trace=trace_out,
             ladder_history=np.stack(ladder_history),
             n_sweeps=done * spi,
             stopped_early=stopped,
         )
+        if eo is not None:
+            # the summary has read the last chunk's stats: the counters are
+            # on the host already, and the profiler has the run's device work
+            if self.config.track_stats:
+                eo.record_rungs(self._pooled_counters(state))
+            if profiling:
+                eo.obs.stop_jax_profile()
         return state, result
+
+    def _retuned(self, state: EngineState, temps: np.ndarray) -> EngineState:
+        """State on a retuned ladder, with the moment accumulators restarted.
+
+        Per-rung means/vars must not pool samples drawn at two different
+        ladders (swap counters stay — the adapt window is baselined, and
+        flow/round-trip labels are chain state).  The weight totals are part
+        of the moment state — a stale weight_sum would deflate post-retune
+        variances and freeze the weighted (VMPT) mean updates.
+        """
+        zeros = lambda tree: jax.tree_util.tree_map(jnp.zeros_like, tree)
+        stats = dataclasses.replace(
+            state.stats,
+            n_records=zeros(state.stats.n_records),
+            weight_sum=zeros(state.stats.weight_sum),
+            mean=zeros(state.stats.mean),
+            m2=zeros(state.stats.m2),
+        )
+        return self.place(dataclasses.replace(
+            state,
+            stats=stats,
+            betas=jnp.asarray(1.0 / temps, jnp.float32),
+        ))
 
     def _pooled_counters(self, state: EngineState) -> dict[str, np.ndarray]:
         """Feedback counters pooled over the ensemble axis (host numpy).

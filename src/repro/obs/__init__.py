@@ -6,7 +6,8 @@ The subsystem behind "where does a PT round spend its time" (DESIGN.md
 * `repro.obs.metrics`  — thread-safe labeled counters/gauges/histograms with
   cheap snapshot semantics;
 * `repro.obs.timeline` — span recorder emitting Chrome trace-event JSON
-  (load in ui.perfetto.dev), per-thread + virtual tracks, flow arrows;
+  (load in ui.perfetto.dev), per-thread + virtual tracks, flow arrows; every
+  span also lands in a running `jax.profiler` trace, on its clock;
 * `repro.obs.export`   — Prometheus text / canonical JSON exposition and
   the snapshot digest benchmarks stamp into their records.
 
@@ -15,11 +16,13 @@ instrumented components accept (`Engine(obs=...)`, `Scheduler(obs=...)`,
 `ObsCallback`).  The overhead contract every consumer relies on:
 
 * **off is structurally free** — components hold ``obs=None`` and guard
-  every instrumentation site with one `is None` check: no recorder objects,
-  no dict churn, no extra device traffic (pinned by ``tests/test_obs.py``);
-* **on is cheap** — spans are one dict append, metrics one locked float op;
-  the engine's per-chunk obs work is <5% of chunk wall time at smoke size
-  (gated by ``benchmarks/obs_overhead.py`` in CI);
+  every metric site with one `is None` check, and hold the `NULL` timeline,
+  whose spans reach only a running profiler: no recorder objects, no dict
+  churn, no extra device traffic (pinned by ``tests/test_obs.py``);
+* **on is cheap and never waits for the device** — spans are one dict
+  append, metrics one locked float op; the engine's per-chunk obs work is
+  <5% of chunk wall time at smoke size (gated by
+  ``benchmarks/obs_overhead.py`` in CI);
 * **compiled computations are untouched** — instrumentation lives entirely
   in host loops; the mega-step jaxpr is byte-identical with obs on or off
   (pinned by ``tests/test_obs.py``).
@@ -60,9 +63,11 @@ class Observability:
     """One registry + one timeline: the handle instrumented code accepts.
 
     ``jax_profile_dir`` arms a one-shot `jax.profiler` window: the first
-    engine chunk after arming runs under ``start_trace``/``stop_trace`` and
-    lands a TensorBoard-loadable profile in the directory.  One chunk only —
-    the profiler's own overhead must not pollute the rest of the timeline.
+    `Engine.run` after arming runs under ``start_trace``/``stop_trace`` and
+    lands a TensorBoard-loadable profile in the directory.  The window
+    closes once the run has read its last chunk's statistics back, so it
+    holds all of the run's device work; one run only — the profiler's own
+    overhead must not pollute the rest of the timeline.
     """
 
     metrics: MetricsRegistry

@@ -15,29 +15,55 @@ Trace Event Format consumed by Perfetto (ui.perfetto.dev) and
   still yields a loadable file of everything that *finished*.
 * **flow events** — ``"ph": "s"/"t"/"f"`` arrows stitch one logical object
   (a serve job: PENDING → RUNNING → DONE) across tracks.
-* **recording cost** — one dict append under a lock per event.  The
-  zero-overhead-off contract lives a level up: disabled components hold *no
-  recorder at all* (`Engine.obs is None`), so this module's cost is only
-  ever paid by runs that asked for a timeline.
+* **one clock with the profiler** — every span also opens a
+  `jax.profiler.TraceAnnotation` of the same name, so a running profiler
+  (``jax.profiler.start_trace``, TensorBoard, xprof) holds the program's
+  host spans beside the device's ops.  Timestamps are `time.time_ns()`, the
+  clock the profiler stamps host events with, so a span's ``ts`` and its
+  profiler event start at the same instant.  `now()` reads that clock for
+  `Timeline.complete`, the one entry that records a span after the fact
+  (and so cannot reach the profiler).
+* **recording cost** — one dict append under a lock per event.  With
+  observability off, components hold `NULL`, whose spans go to the
+  profiler alone: they record nothing when no profiler runs.
 
-Timestamps are `time.perf_counter()` microseconds relative to the Timeline's
-creation; `write()` lands atomically (tmp + rename).  The file passes
+`write()` lands atomically (tmp + rename).  The file passes
 `repro.obs.check_trace` — the schema gate CI runs on the smoke timeline.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
 
-__all__ = ["Timeline", "NullTimeline", "NULL"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Timeline", "NullTimeline", "NULL", "ProfilerSpan", "now", "gc_spans"]
+
+
+def now() -> float:
+    """Seconds on the profiler's host clock (`time.time_ns`), the start that
+    `Timeline.complete` takes."""
+    return time.time_ns() * 1e-9
+
+
+class ProfilerSpan(TraceAnnotation):
+    """A span that only a running profiler sees (`NullTimeline.span`)."""
+
+    __slots__ = ()
+
+    def annotate(self, **kv) -> "ProfilerSpan":
+        self.set_metadata(**kv)
+        return self
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, and the
+    same span in a running profiler."""
 
-    __slots__ = ("_tl", "name", "cat", "track", "args", "_t0")
+    __slots__ = ("_tl", "name", "cat", "track", "args", "_t0", "_prof")
 
     def __init__(self, tl: "Timeline", name, cat, track, args):
         self._tl = tl
@@ -47,7 +73,9 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._prof = ProfilerSpan(self.name, **(self.args or {}))
+        self._t0 = now()
+        self._prof.__enter__()
         return self
 
     def annotate(self, **kv) -> "_Span":
@@ -55,12 +83,14 @@ class _Span:
         if self.args is None:
             self.args = {}
         self.args.update(kv)
+        self._prof.set_metadata(**kv)
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
         if exc_type is not None:
             self.annotate(error=exc_type.__name__)
+        self._prof.__exit__(exc_type, exc, tb)
+        t1 = now()
         self._tl.complete(
             self.name, self._t0, t1 - self._t0,
             cat=self.cat, track=self.track, args=self.args,
@@ -74,7 +104,6 @@ class Timeline:
     def __init__(self):
         self._lock = threading.Lock()
         self._events: list[dict] = []
-        self._t0 = time.perf_counter()
         self._pid = os.getpid()
         self._tracks: dict[str, int] = {}  # track name -> tid
         self.enabled = True
@@ -96,7 +125,8 @@ class Timeline:
         return tid
 
     def _ts(self, t: float | None = None) -> float:
-        return ((time.perf_counter() if t is None else t) - self._t0) * 1e6
+        """Microseconds on the profiler's clock (``t`` in `now()` seconds)."""
+        return (now() if t is None else t) * 1e6
 
     def _emit(self, ev: dict) -> None:
         with self._lock:
@@ -111,8 +141,10 @@ class Timeline:
     def complete(self, name: str, start: float, duration: float, *,
                  cat: str = "engine", track: str | None = None,
                  args: dict | None = None) -> None:
-        """Record a finished span from explicit perf_counter start/duration
-        (for begin/end pairs that cross callback boundaries, e.g. phases)."""
+        """Record a finished span from an explicit start (`now()` seconds)
+        and duration.  It reaches no profiler: use `span` wherever the work
+        can sit inside a ``with`` block (the serve scheduler's bucket lanes
+        and recoveries cannot)."""
         ev = {
             "name": name, "ph": "X", "cat": cat, "pid": self._pid,
             "tid": self._tid(track), "ts": self._ts(start),
@@ -193,38 +225,19 @@ class Timeline:
         return path
 
 
-class _NullSpan:
-    """Reusable no-op span: no allocation per `span()` call."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def annotate(self, **kv):
-        return self
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTimeline:
-    """API-compatible no-op recorder.
+    """API-compatible recorder that keeps nothing.
 
-    Components that *sometimes* record can hold this instead of branching on
-    None at every site; every method returns immediately and `span()` hands
-    back one shared reusable object — structurally zero per-call allocation.
-    (The engine host loop goes further and holds no recorder at all when
-    observability is off.)
+    Components that *sometimes* record hold this instead of branching on
+    None at every site.  Every method returns immediately, except `span()`:
+    it hands back a `ProfilerSpan`, so the span still reaches a running
+    profiler and costs one small object when none runs.
     """
 
     enabled = False
 
     def span(self, name, cat="engine", track=None, **args):
-        return _NULL_SPAN
+        return ProfilerSpan(name, **args)
 
     def complete(self, *a, **k):
         pass
@@ -261,3 +274,43 @@ class NullTimeline:
 
 
 NULL = NullTimeline()
+
+
+class _GcSpans:
+    """``repro.python.gc`` profiler spans from a `gc.callbacks` hook.
+
+    Entered by every `Engine.run`; the hook is installed by the first
+    entry and removed when the last one leaves.  A collection holds the
+    GIL from its start to its stop, so one open span at a time suffices.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._users = 0
+        self._open: ProfilerSpan | None = None
+
+    def _hook(self, phase, info):
+        if phase == "start":
+            self._open = ProfilerSpan("repro.python.gc",
+                                      generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+    def __enter__(self):
+        with self._lock:
+            if self._users == 0:
+                gc.callbacks.append(self._hook)
+            self._users += 1
+        return self
+
+    def __exit__(self, *exc):
+        with self._lock:
+            self._users -= 1
+            if self._users == 0:
+                gc.callbacks.remove(self._hook)
+        return False
+
+
+gc_spans = _GcSpans()

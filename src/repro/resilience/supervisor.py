@@ -38,6 +38,7 @@ import threading
 import time
 from typing import Any, Callable
 
+from repro.obs.timeline import now
 from repro.resilience.faults import FaultError
 
 __all__ = [
@@ -173,12 +174,12 @@ class Supervisor:
                         bucket=bucket, finished=True, retries=attempt - 1,
                         quarantined=True, error=err, recoveries=recoveries,
                     )
-                t0 = time.perf_counter()
+                t0 = now()  # the timeline's clock: the recovery becomes a span
                 self._sleep(self.policy.delay(
                     getattr(bucket, "name", bucket.digest), attempt
                 ))
                 bucket = bucket.recover()
-                dt = time.perf_counter() - t0
+                dt = now() - t0
                 depth = getattr(bucket, "restore_fallback_depth", 0)
                 recoveries.append({
                     "t0": t0,

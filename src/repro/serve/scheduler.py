@@ -49,6 +49,7 @@ from typing import Any, Callable
 from repro.api.spec import RunSpec
 from repro.checkpoint.manager import CheckpointManager
 from repro.engine import Engine
+from repro.obs.timeline import ProfilerSpan, now as timeline_now
 from repro.resilience import RetryPolicy, Supervisor
 from repro.serve.bucket import (
     MANIFEST_NAME,
@@ -311,8 +312,8 @@ class Scheduler:
                     system, template.observables
                 ),
                 # packed engines share the scheduler's telemetry bundle, so
-                # engine spans (compile, chunk, device_wait) land on the
-                # same trace as the quantum lanes
+                # engine spans (repro.engine.compile, .chunk, .dispatch)
+                # land on the same trace as the quantum lanes
                 obs=self._obs,
                 faults=self._faults,
                 strict_kernels=self._strict_kernels,
@@ -379,8 +380,11 @@ class Scheduler:
             job.state = JobState.RUNNING
         self.quantum_log.append(bucket.digest)
         lane = f"bucket:{bucket.digest[:8]}"
-        t0 = time.perf_counter()
-        out = self._supervisor.run(bucket, self.quantum_chunks)
+        t0 = timeline_now()
+        # the lane span is recorded after the fact (`complete`); the
+        # profiler sees the quantum as it runs
+        with ProfilerSpan("repro.serve.quantum", bucket=bucket.digest[:8]):
+            out = self._supervisor.run(bucket, self.quantum_chunks)
         if out.bucket is not bucket:
             # a recovered generation replaced the instance we passed in —
             # move the quantum bookkeeping over with it
@@ -389,11 +393,11 @@ class Scheduler:
             )
             bucket = out.bucket
         finished = out.finished
-        dt = time.perf_counter() - t0
+        dt = timeline_now() - t0
         self._m_quantum.observe(dt)
         self._m_quanta.inc()
         self._timeline.complete(
-            "quantum", t0, dt, cat="serve", track=lane,
+            "repro.serve.quantum", t0, dt, cat="serve", track=lane,
             args={"jobs": len(bucket.jobs), "finished": finished,
                   "retries": out.retries, "quarantined": out.quarantined},
         )
@@ -401,7 +405,7 @@ class Scheduler:
             self._m_retries.inc(out.retries)
         for rec in out.recoveries:
             self._timeline.complete(
-                "recovery", rec["t0"], rec["seconds"], cat="serve",
+                "repro.serve.recovery", rec["t0"], rec["seconds"], cat="serve",
                 track=lane,
                 args={"error": rec["error"], "sweep": rec["sweep"],
                       "fallback_depth": rec["fallback_depth"]},

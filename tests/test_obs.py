@@ -8,15 +8,21 @@ The pins, in dependency order:
   expansion; the snapshot digest is deterministic and content-sensitive;
 * **timeline** — spans/instants/flows land as schema-valid Chrome trace
   events, tracks get stable tids + thread_name metadata, `write` round-trips
-  through the `check_trace` validator; `NullTimeline` allocates nothing;
+  through the `check_trace` validator; `NullTimeline` records nothing and
+  its spans reach a running profiler; a span starts at the same instant in
+  the timeline and in the profiler;
 * **zero-overhead-off** — an engine with ``obs=None`` constructs no
   `_EngineObs`, records no events and touches no metric even when the obs
   classes are booby-trapped to raise; the mega-step jaxpr is byte-identical
   with obs on or off, and an instrumented run is *bit-equal* to a bare one;
-* **obs-on** — the engine's spans and counters actually appear (compile /
-  device_wait / chunk / adapt / checkpoint), `ObsCallback` lands artifacts
-  on disk through a full Session, and `Scheduler.metrics()` exposes the
+* **obs-on** — the engine's spans and counters actually appear
+  (repro.engine.compile / .chunk / .dispatch / .adapt / .checkpoint), the
+  host loop never waits for the device, `ObsCallback` lands artifacts on
+  disk through a full Session, and `Scheduler.metrics()` exposes the
   serve-side series;
+* **profiler** — with obs off or on, a profiled engine run holds one
+  repro.engine.dispatch and one repro.engine.callbacks span per chunk,
+  inside repro.engine.chunk, and a repro.engine.compile span;
 * **diagnostics fallback** — legacy traces without a `swap_attempt` channel
   warn when the `prob > 0` inference kicks in; engine-era traces don't.
 
@@ -45,7 +51,7 @@ from repro.obs import (
     write_prometheus,
 )
 from repro.obs.check_trace import TraceError, validate_trace
-from repro.obs.timeline import _NULL_SPAN
+from repro.obs.timeline import ProfilerSpan
 
 R, L = 4, 4
 TEMPS = np.asarray(ladder.linear_ladder(R, 1.5, 3.5))
@@ -248,9 +254,11 @@ def test_write_roundtrips_through_validator(tmp_path):
     assert summary["open_flows"] == 0
 
 
-def test_null_timeline_is_inert_and_allocation_free():
+def test_null_timeline_is_inert_and_allocation_free(tmp_path):
+    """The null span keeps nothing of its own: with no profiler it records
+    nothing, and a running profiler receives it with its args."""
     nt = NullTimeline()
-    assert nt.span("a") is nt.span("b") is _NULL_SPAN
+    assert isinstance(nt.span("a"), ProfilerSpan)
     with nt.span("a") as sp:
         assert sp.annotate(x=1) is sp
     nt.instant("x")
@@ -259,6 +267,62 @@ def test_null_timeline_is_inert_and_allocation_free():
     assert len(nt) == 0 and nt.events() == []
     with pytest.raises(RuntimeError, match="records nothing"):
         nt.write("/tmp/never.json")
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with nt.span("repro.test.null", k=3) as sp:
+            sp.annotate(extra=1)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(nt) == 0
+    (ev,) = [e for e in _profiled_host_events(tmp_path)
+             if e[0] == "repro.test.null"]
+    assert ev[3] == {"k": 3, "extra": 1}
+
+
+def _profiled_host_events(directory):
+    """``(name, start_ns, end_ns, args)`` of every host event in the newest
+    profile under ``directory``, start and end on `time.time_ns`'s clock."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    data = ProfileData.from_file(path)
+    t0 = next(dict(p.stats)["profile_start_time"] for p in data.planes
+              if p.name == "Task Environment")
+    out = []
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        out.append((e.name, t0 + e.start_ns,
+                                    t0 + e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+    return out
+
+
+def test_span_starts_with_its_profiler_event(tmp_path):
+    """One span, recorded by the timeline and by the profiler, starts at
+    the same instant on both (within 50 us)."""
+    tl = Timeline()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(5):
+            with tl.span(f"repro.test.clock{i}"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    prof = {n: (a, b) for n, a, b, _ in _profiled_host_events(tmp_path)}
+    spans = [e for e in tl.events() if e["ph"] == "X"]
+    assert len(spans) == 5
+    for ev in spans:
+        start_ns, end_ns = prof[ev["name"]]
+        assert abs(ev["ts"] * 1e3 - start_ns) < 50e3, ev["name"]
+        assert abs((ev["ts"] + ev["dur"]) * 1e3 - end_ns) < 50e3, ev["name"]
 
 
 # ---------- trace validator -----------------------------------------------------
@@ -361,6 +425,42 @@ def test_obs_on_run_bit_equal_to_obs_off():
     )
 
 
+def test_cached_mega_step_keeps_its_own_scopes(tmp_path, monkeypatch):
+    """The pt.* scopes are debug info, which JAX's persistent cache key
+    leaves out by default.  A cache that already holds the same program
+    compiled without them must not hand that executable back: the
+    engine's executable carries its own scopes."""
+    import contextlib
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    cc.reset_cache()
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+        def compiled():
+            eng = _engine(chunk_intervals=1)
+            return eng._compiled(eng.init(jax.random.key(0), TEMPS), 1).as_text()
+
+        with monkeypatch.context() as m:  # the same program, unscoped
+            m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+            assert "pt.sweep" not in compiled()
+        assert any(tmp_path.iterdir())
+        text = compiled()
+        for scope in ("pt.sweep", "pt.rng", "pt.exchange", "pt.stats", "pt.observe"):
+            assert scope in text, scope
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
 def test_obs_detach_restores_bare_engine():
     eng = _engine(obs=Observability.create(timeline=False))
     assert isinstance(eng._eobs, _EngineObs)
@@ -396,20 +496,67 @@ def test_engine_metrics_and_spans_populated():
     assert value("engine_compiles_total") == 1
     assert value("engine_chunks_total") == 4
     assert value("engine_sweeps_total") == 16
-    assert snap["engine_chunk_seconds"]["samples"][0]["count"] == 4
+    # one sample per pair of successive dispatch returns
+    assert snap["engine_chunk_seconds"]["samples"][0]["count"] == 3
     assert value("engine_compile_seconds_total") > 0
+    for gone in ("engine_device_seconds_total", "engine_host_seconds_total",
+                 "engine_sweeps_per_sec"):
+        assert gone not in snap
     # live per-rung gauges: R-1 pair children, R rung children
     assert len(snap["pt_swap_acceptance"]["samples"]) == R - 1
     assert len(snap["pt_flow_up_fraction"]["samples"]) == R
 
-    names = {e["name"] for e in obs.timeline.events() if e["ph"] == "X"}
-    assert {"compile", "device_wait", "chunk"} <= names
-    chunk_ev = next(
-        e for e in obs.timeline.events()
-        if e["ph"] == "X" and e["name"] == "chunk"
-    )
-    # lattice systems annotate the modeled HBM traffic per chunk launch
-    assert chunk_ev["args"]["modeled_hbm_bytes"] > 0
+    spans = [e for e in obs.timeline.events() if e["ph"] == "X"]
+    names = [e["name"] for e in spans]
+    assert names.count("repro.engine.compile") == 1
+    assert names.count("repro.engine.chunk") == 4
+    assert names.count("repro.engine.dispatch") == 4
+    assert "device_wait" not in names
+    chunk_ev = next(e for e in spans if e["name"] == "repro.engine.chunk")
+    assert chunk_ev["args"] == {"chunk": 1, "intervals": 2}
+
+
+def test_obs_on_host_loop_never_waits_for_the_device(monkeypatch):
+    """The instrumented loop dispatches chunk after chunk with no
+    `block_until_ready`: the device/host split comes from the trace."""
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    for obs in (None, Observability.create(timeline=True)):
+        eng = _engine(obs=obs)
+        st = eng.init(jax.random.key(2), TEMPS)
+        eng.run(st, 16)
+    assert calls == []
+
+
+@pytest.mark.parametrize("with_obs", [False, True], ids=["obs_off", "obs_on"])
+def test_engine_spans_reach_the_profiler(tmp_path, with_obs):
+    """Profiled run of a small engine: one dispatch and one callbacks span
+    per chunk, each inside its chunk span, and the compile span."""
+    obs = Observability.create(timeline=True) if with_obs else None
+    eng = _engine(obs=obs)
+    st = eng.init(jax.random.key(4), TEMPS)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(st, 16, on_chunk=lambda info: False)  # 4 chunks
+    finally:
+        jax.profiler.stop_trace()
+    events = _profiled_host_events(tmp_path)
+    named = lambda n: sorted((a, b) for name, a, b, _ in events if name == n)
+    chunks = named("repro.engine.chunk")
+    assert len(chunks) == 4 and len(named("repro.engine.compile")) == 1
+    for child in ("repro.engine.dispatch", "repro.engine.callbacks"):
+        inner = named(child)
+        assert len(inner) == 4, child
+        for (a, b), (ca, cb) in zip(chunks, inner):
+            assert a <= ca <= cb <= b, child
+    (ca, cb), (ka, kb) = chunks[0], named("repro.engine.compile")[0]
+    assert ca <= ka <= kb <= cb
 
 
 def test_engine_checkpoint_span_and_counter(tmp_path):
@@ -423,7 +570,7 @@ def test_engine_checkpoint_span_and_counter(tmp_path):
     snap = obs.metrics.snapshot()
     assert snap["engine_checkpoints_total"]["samples"][0]["value"] == 2
     names = [e["name"] for e in obs.timeline.events() if e["ph"] == "X"]
-    assert names.count("checkpoint") == 2
+    assert names.count("repro.engine.checkpoint") == 2
 
 
 # ---------- ObsCallback through a full Session ----------------------------------
@@ -456,10 +603,14 @@ def test_obs_callback_writes_artifacts_through_session(tmp_path):
     Session(_spec(), callbacks=[cb]).run()
 
     with open(trace_path) as f:
-        summary = validate_trace(json.load(f), require_spans=[
-            "compile", "chunk", "device_wait", "phase:burn", "phase:measure",
-        ])
-    assert "session" in summary["tracks"]
+        data = json.load(f)
+    validate_trace(data, require_spans=[
+        "repro.engine.compile", "repro.engine.chunk", "repro.engine.dispatch",
+        "repro.session.phase",
+    ])
+    phases = [e["args"]["phase"] for e in data["traceEvents"]
+              if e.get("name") == "repro.session.phase"]
+    assert phases == ["burn", "measure"]
     text = open(prom_path).read()
     # 2 phases x 8 sweeps = 2 phases x 2 chunks of 2 intervals
     assert "engine_chunks_total 4" in text
