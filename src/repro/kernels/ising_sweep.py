@@ -41,9 +41,23 @@ Three kernels share that tile strategy (DESIGN.md §6):
   per-swap kernel exit + host round-trip entirely; with ``n_rounds > 1``
   the spin block stays VMEM-resident across multiple exchanges.
 
+The fused and round kernels sweep the lattice **split by colour**
+(`repro.kernels.lattice`): two (L/2, L) half-planes ``X_c[k, j] = s[2k +
+((j + c) & 1), j]``, split once per launch and merged at its end, so each
+half-sweep draws, computes and compares only the L²/2 sites of its colour
+instead of the whole plane masked to half.  The site counter feeding the
+threefry draw is each element's real linear index ``(2k + ((j + c) &
+1))·L + j``, built once per launch, and the acceptance arithmetic is the
+same per site, so the stream is unchanged: the split launches are bit-equal
+to the whole-plane sweep fed `prng.ising_sweep_uniforms`.  Their
+operations are named ``ising_sweep_fused_split`` and
+``ising_round_fused_split`` in a profile.  The per-sweep kernel, whose
+uniforms arrive as whole planes, keeps the whole-plane body, as does
+bit-plane packing.
+
 ΔE totals come from integer-valued sums over the flipped sites (Σ s·nbr
 and Σ s; `_flip_energy`), exact in any reduction order up to 2^23 sites,
-so every backend and both storage layouts give the same bits.  The
+so every backend and every storage layout gives the same bits.  The
 oracle sums f32 per-site values in its backend's own order, so kernel and
 oracle ΔE agree to rounding; spins and acceptance counts are exact.
 
@@ -55,7 +69,9 @@ to the unpacked path (pinned by tests).
 
 VMEM working set per grid step (bytes; pinned by tests/test_kernels.py and
 checked by the tile sweep).  The models count the whole block's working
-copies, an upper bound now that the kernels sweep one replica at a time:
+copies at the whole plane's size, an upper bound now that the kernels
+sweep one replica, and on the fused paths one colour half-plane, at a
+time:
 
 * per-sweep: r_blk · L² · (2 int8 in/out + 2·4 u-f32 + 4 f32 widened +
   4 f32 neighbour-sum) = 18·r_blk·L²; L=300, r_blk=8 ≈ 12.4 MiB — just
@@ -82,6 +98,8 @@ from repro.kernels.lattice import (
     roll1,
     round_call,
     site_sum,
+    split_neighbours,
+    swap_odd_columns,
     sweep_call,
 )
 
@@ -110,29 +128,74 @@ def _flip_energy(s_nbr, s_sum, j, b):
     return 2.0 * de
 
 
-def _ising_sweep_body(s, beta, parity, draw, *, j, b, rule):
-    """One checkerboard sweep (two half-sweeps) on a widened f32 spin group.
+def _flip(s, nbr, u, beta, active, *, j, b, rule):
+    """Metropolis/Glauber flips of the spins ``s`` given their neighbour
+    sums and uniforms, restricted to ``active`` unless it is None.
 
-    Shared by all three kernels: ``draw(colour)`` reads the uniforms from
-    the input stream or from the counter PRNG.  ``beta`` is a (g, 1, 1)
-    column.  Returns ``(s', delta_e (g, 1, 1), n_accepted (g, 1, 1))``.
+    Returns ``(s', delta_e (g, 1, 1), n_accepted (g, 1, 1))``.
+    """
+    de = 2.0 * s * (j * nbr - b)
+    accept = u < accept_prob(de, beta, rule)
+    if active is not None:
+        accept = accept & active
+    flipped = jnp.where(accept, s, 0.0)
+    s_sum = site_sum(flipped) if b else None
+    ds = _flip_energy(site_sum(flipped * nbr), s_sum, j, b)
+    na = site_sum(accept.astype(jnp.int32))
+    return jnp.where(accept, -s, s), ds, na
+
+
+def _ising_sweep_body(s, beta, parity, draw, *, j, b, rule):
+    """One checkerboard sweep (two half-sweeps) on a widened f32 spin plane.
+
+    The per-sweep kernel's body: ``draw(colour)`` reads the whole plane's
+    uniforms from the input stream, and each half-sweep masks its colour
+    with the (H, W) ``parity`` map.  ``beta`` is a (g, 1, 1) column.
+    Returns ``(s', delta_e (g, 1, 1), n_accepted (g, 1, 1))``.
     """
     ds = jnp.zeros(beta.shape, jnp.float32)
     na = jnp.zeros(beta.shape, jnp.int32)
     for color in (0, 1):  # static unroll: two half-sweeps, one HBM round-trip
-        u = draw(color)
         nbr = roll1(s, 1, 1) + roll1(s, -1, 1) + roll1(s, 1, 2) + roll1(s, -1, 2)
-        de = 2.0 * s * (j * nbr - b)
-        accept = (u < accept_prob(de, beta, rule)) & (parity == color)
-        flipped = jnp.where(accept, s, 0.0)
-        s_sum = site_sum(flipped) if b else None
-        ds = ds + _flip_energy(site_sum(flipped * nbr), s_sum, j, b)
-        na = na + site_sum(accept.astype(jnp.int32))
-        s = jnp.where(accept, -s, s)
+        s, d, n = _flip(
+            s, nbr, draw(color), beta, parity == color, j=j, b=b, rule=rule
+        )
+        ds, na = ds + d, na + n
     return s, ds, na
 
 
-def _kind(j, b, rule, pack_bits=False) -> Kind:
+def _ising_sweep_body_split(x, beta, colours, draw, *, j, b, rule):
+    """`_ising_sweep_body` on the colour-split layout (`repro.kernels.lattice`).
+
+    ``x`` is the pair of (g, H/2, W) colour half-planes, ``colours`` their
+    column parity and ``draw(c)`` colour c's uniforms at its sites' own
+    counters.  Every element of a half-plane is a site of its colour, so
+    nothing is masked and each half-sweep computes half the plane.
+    """
+    x = list(x)
+    ds = jnp.zeros(beta.shape, jnp.float32)
+    na = jnp.zeros(beta.shape, jnp.int32)
+    for color in (0, 1):
+        nbr = split_neighbours(x[1 - color], colours, color)
+        x[color], d, n = _flip(
+            x[color], nbr, draw(color), beta, None, j=j, b=b, rule=rule
+        )
+        ds, na = ds + d, na + n
+    return tuple(x), ds, na
+
+
+def _plane_kind(j, b, rule) -> Kind:
+    """The per-sweep kernel's whole-plane Ising sweep."""
+    return Kind(
+        load=_widen,
+        step=functools.partial(_ising_sweep_body, j=j, b=b, rule=rule),
+        store=lambda s, _n: _narrow(s),
+    )
+
+
+def _fused_kind(j, b, rule, pack_bits) -> Kind:
+    """The fused and round kernels' Ising sweep: bit-plane packed, or on
+    the colour-split layout."""
     if pack_bits:
         return Kind(
             load=lambda s8: _pack_spins(_widen(s8)),
@@ -141,10 +204,19 @@ def _kind(j, b, rule, pack_bits=False) -> Kind:
             whole_block=True,
         )
     return Kind(
-        load=_widen,
-        step=functools.partial(_ising_sweep_body, j=j, b=b, rule=rule),
-        store=lambda s, _n: _narrow(s),
+        load=lambda s8: swap_odd_columns(_widen(s8[:, 0]), _widen(s8[:, 1])),
+        step=functools.partial(_ising_sweep_body_split, j=j, b=b, rule=rule),
+        store=lambda x, _n: jnp.stack(
+            [_narrow(p) for p in swap_odd_columns(*x)], axis=1
+        ),
+        colour_split=True,
     )
+
+
+def _launch_name(stem: str, pack_bits: bool) -> str | None:
+    """The kernel operation's name in a profile: the colour-split launches
+    say so; a packed launch keeps its wrapper's name."""
+    return None if pack_bits else f"{stem}_split"
 
 
 def ising_sweep_pallas(
@@ -167,7 +239,8 @@ def ising_sweep_pallas(
       interpret: True on CPU; False on real TPU.
     """
     return sweep_call(
-        _kind(j, b, rule), spins, u, betas, r_blk=r_blk, interpret=interpret
+        _plane_kind(j, b, rule), spins, u, betas, r_blk=r_blk,
+        interpret=interpret,
     )
 
 
@@ -346,9 +419,10 @@ def ising_sweep_fused_pallas(
     if replica_offset is None:
         replica_offset = jnp.zeros((1,), jnp.uint32)
     return fused_call(
-        _kind(j, b, rule, pack_bits), spins, key_words, t0, betas,
+        _fused_kind(j, b, rule, pack_bits), spins, key_words, t0, betas,
         n_sweeps=n_sweeps, replica_offset=replica_offset, r_blk=r_blk,
         interpret=interpret,
+        name=_launch_name("ising_sweep_fused", pack_bits),
     )
 
 
@@ -392,9 +466,10 @@ def ising_round_fused_pallas(
     int32 0/1).
     """
     return round_call(
-        _kind(j, b, rule, pack_bits), spins, key_words, t0, phase0, rung,
-        energy, betas, n_sweeps=n_sweeps, n_rounds=n_rounds,
+        _fused_kind(j, b, rule, pack_bits), spins, key_words, t0, phase0,
+        rung, energy, betas, n_sweeps=n_sweeps, n_rounds=n_rounds,
         criterion=criterion, pairing=pairing, interpret=interpret,
+        name=_launch_name("ising_round_fused", pack_bits),
     )
 
 

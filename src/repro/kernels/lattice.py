@@ -32,6 +32,31 @@ Mosaic's rules shape the interfaces:
 * the scoped-VMEM limit is raised from its 16 MiB default
   (`VMEM_LIMIT_BYTES`), which the whole-block DMA buffers at the paper's
   L=300 approach once Mosaic pads the lattice to (8, 128) tiles.
+
+The colour-split layout.  A checkerboard half-sweep updates only the sites
+of one colour, so a sweep over the whole (L, L) plane masked to one colour
+computes twice the work it keeps.  A `Kind` with ``colour_split`` is swept
+on two compacted half-planes instead, split along rows so that each keeps
+all L lanes::
+
+    X_c[k, j] = s[2k + ((j + c) & 1), j]        k < L/2, j < L
+
+A site of colour c at X_c[k, j] has its four neighbours in Y = X_{1-c}:
+Y[k, j-1] and Y[k, j+1] along the lanes, and along the sublanes Y[k, j]
+and, as p = (j + c) & 1 is 0 or 1, Y[k-1, j] or Y[k+1, j]
+(`split_neighbours`).  Periodic wrap holds because L is even, which a
+checkerboard needs anyway.  `fused_call` and `round_call` hand the kernel
+the lattice's even and odd rows as two planes (`split_rows`,
+`merge_rows`: a transpose that XLA folds into the layout copy it makes
+around the kernel anyway); the kernel swaps their odd columns
+(`swap_odd_columns`) into the colour half-planes as it loads a replica,
+and back as it stores it, and the carry holds both half-planes through
+all the launch's sweeps.  Each element draws its
+uniform at its site's linear index ``(2k + ((j + c) & 1))·L + j``
+(`colour_split_geometry`, built once per launch), which is the counter the
+whole-plane draw gives that site; the acceptance arithmetic is the same
+per site, so spins, acceptance counts and ΔE are those of the whole-plane
+sweep, bit for bit.
 """
 from __future__ import annotations
 
@@ -52,19 +77,29 @@ VMEM_LIMIT_BYTES = 100 * 2**20  # of a v5e TensorCore's 128 MiB
 class Kind(NamedTuple):
     """What a lattice system contributes to the shared kernels.
 
-    ``load`` widens an int8 (g, H, W) group into the sweep's carry and
-    ``store`` narrows it back (``store(carry, g)``); ``step(carry, beta,
-    parity, draw)`` is one checkerboard sweep, where ``draw(plane)`` gives
-    that plane's (g, H, W) uniforms and ``beta`` is a (g, 1, 1) column; it
-    returns ``(carry', delta_e, n_accepted)`` with (g, 1, 1) totals.
-    ``whole_block`` makes the whole block one group, for storage that spans
-    replicas.
+    ``load`` widens an int8 group of the kernel's lattice block into the
+    sweep's carry and ``store`` narrows it back (``store(carry, g)``);
+    ``step(carry, beta, colours, draw)`` is one checkerboard sweep, where
+    ``draw(plane)`` gives that plane's uniforms and ``beta`` is a (g, 1, 1)
+    column; it returns ``(carry', delta_e, n_accepted)`` with (g, 1, 1)
+    totals.  ``whole_block`` makes the whole block one group, for storage
+    that spans replicas.
+
+    ``colour_split`` picks the fused and round launches' layout.  False:
+    the block is (g, H, W), ``colours`` is the (H, W) checkerboard map and
+    each ``draw`` is a whole (g, H, W) plane.  True: the block is the
+    (g, 2, H/2, W) pair of row planes (`split_rows`), which ``load`` turns
+    into the colour half-planes and ``store`` back (module docstring),
+    ``colours`` is the (H/2, W) column parity ``j & 1`` and ``draw(c)``
+    gives colour c's (g, H/2, W) uniforms.  The per-sweep launch always
+    takes the whole plane.
     """
 
     load: Callable
     step: Callable
     store: Callable
     whole_block: bool = False
+    colour_split: bool = False
 
 
 # -- kernel-body helpers (Mosaic-safe) -----------------------------------------
@@ -98,6 +133,76 @@ def parity(h: int, w: int) -> jnp.ndarray:
     return (ii + jj) % 2
 
 
+def require_even(shape) -> None:
+    """Refuse a lattice that periodic wrap leaves without a checkerboard.
+
+    On an odd side the wrap-around neighbours share a colour, so a
+    half-sweep would flip interacting sites at once.
+    """
+    h, w = shape[-2:]
+    if h % 2 or w % 2:
+        raise ValueError(
+            f"a checkerboard sweep needs even lattice sides under periodic "
+            f"wrap, got {h}x{w}"
+        )
+
+
+def split_rows(lattice: jnp.ndarray) -> jnp.ndarray:
+    """(..., H, W) lattice -> (..., 2, H/2, W) row planes: plane p holds
+    rows 2k + p.  A transpose, outside any kernel, that XLA folds into the
+    layout copy it makes around the kernel anyway."""
+    require_even(lattice.shape)
+    *lead, h, w = lattice.shape
+    return jnp.swapaxes(lattice.reshape(*lead, h // 2, 2, w), -3, -2)
+
+
+def merge_rows(planes: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of `split_rows`: (..., 2, H/2, W) -> (..., H, W)."""
+    *lead, _, h2, w = planes.shape
+    return jnp.swapaxes(planes, -3, -2).reshape(*lead, 2 * h2, w)
+
+
+def swap_odd_columns(a: jnp.ndarray, b: jnp.ndarray):
+    """``(where(j even, a, b), where(j even, b, a))`` over the last axis j.
+
+    Takes the row planes (rows 2k, 2k+1) to the colour half-planes
+    ``X_c[k, j] = s[2k + ((j + c) & 1), j]``, and, being its own inverse,
+    the colour half-planes back to the row planes.
+    """
+    even = jax.lax.broadcasted_iota(jnp.int32, a.shape[-2:], 1) % 2 == 0
+    return jnp.where(even, a, b), jnp.where(even, b, a)
+
+
+def colour_split_geometry(h2: int, w: int):
+    """``(colours, sites)`` of the colour-split layout of an (2·h2, w) lattice.
+
+    ``colours`` is the (h2, w) int32 column parity ``j & 1``; ``sites(c)``
+    is colour c's uint32 site counter ``(2k + ((j + c) & 1))·w + j``, the
+    linear index of the site that X_c[k, j] holds.  Built once per launch.
+    """
+    k = prng.iota_u32((h2, w), 0)
+    j = prng.iota_u32((h2, w), 1)
+    one = jnp.uint32(1)
+    counters = tuple(
+        (k + k + ((j + jnp.uint32(c)) & one)) * jnp.uint32(w) + j
+        for c in (0, 1)
+    )
+    colours = jax.lax.broadcasted_iota(jnp.int32, (h2, w), 1) % 2
+    return colours, lambda plane: counters[plane]
+
+
+def split_neighbours(y: jnp.ndarray, colours: jnp.ndarray, colour: int):
+    """4-neighbour sum of colour ``colour``'s sites from the other colour's
+    half-plane ``y`` (g, H/2, W); ``colours`` is the column parity map.
+
+    Lanes: Y[k, j∓1].  Sublanes: Y[k, j] and Y[k-1, j] where
+    ``(j + colour) & 1`` is 0, else Y[k+1, j].
+    """
+    up_first = colours == colour  # (j + colour) & 1 == 0
+    vertical = y + jnp.where(up_first, roll1(y, 1, 1), roll1(y, -1, 1))
+    return vertical + roll1(y, 1, 2) + roll1(y, -1, 2)
+
+
 def site_sum(x: jnp.ndarray) -> jnp.ndarray:
     """(g, H, W) -> (g, 1, 1) per-replica sum over the lattice.
 
@@ -121,21 +226,33 @@ def _for_each_group(n: int, g: int, fn) -> None:
     jax.lax.fori_loop(0, n // g, body, 0)
 
 
-def _fused_sweeps(kind: Kind, lat, beta, rep, sk, t0, n_sweeps, par):
+def _geometry(kind: Kind, block_shape):
+    """``(colours, sites)`` of a launch's (…, H, W) lattice block, built once
+    outside the sweep loop: the map `Kind.step` takes and the site counters
+    ``sites(plane)`` each plane's draw is made at."""
+    h, w = block_shape[-2:]
+    if kind.colour_split:
+        return colour_split_geometry(h, w)
+    site = prng.site_index(h, w)
+    return parity(h, w), lambda plane: site
+
+
+def _fused_sweeps(kind: Kind, lat, beta, rep, sk, t0, n_sweeps, geometry):
     """``n_sweeps`` counter-PRNG sweeps of one group: the shared inner loop.
 
-    ``rep`` is the (g, 1, 1) column of global replica counters and ``t0``
-    the global sweep counter at entry.  ΔE and acceptances accumulate per
-    colour within a sweep, then per sweep, as repeated `ref` application
-    does.  Returns ``(int8 lattice, delta_e, n_accepted)``.
+    ``rep`` is the (g, 1, 1) column of global replica counters, ``t0``
+    the global sweep counter at entry and ``geometry`` the launch's
+    `_geometry`.  ΔE and acceptances accumulate per colour within a sweep,
+    then per sweep, as repeated `ref` application does.  Returns ``(int8
+    lattice, delta_e, n_accepted)``.
     """
-    h, w = par.shape
+    colours, sites = geometry
 
     def sweep(i, carry):
         x, de, na = carry
         w0, w1 = prng.sweep_key(sk[0], sk[1], t0 + _u32(i), rep)
-        draw = lambda plane: prng.plane_uniforms(w0, w1, plane, h, w)
-        x, ds, dn = kind.step(x, beta, par, draw)
+        draw = lambda plane: prng.site_uniforms(w0, w1, plane, sites(plane))
+        x, ds, dn = kind.step(x, beta, colours, draw)
         return x, de + ds, na + dn
 
     x, de, na = jax.lax.fori_loop(
@@ -237,7 +354,7 @@ def _fused_kernel(lat_ref, beta_ref, kw_ref, t0_ref, off_ref, out_ref, de_ref,
     """
     r_blk = lat_ref.shape[0]
     g = r_blk if kind.whole_block else 1
-    par = parity(*lat_ref.shape[-2:])
+    geometry = _geometry(kind, lat_ref.shape)
     sk = prng.stream_key((kw_ref[0, 0], kw_ref[0, 1]))
     first = _u32(pl.program_id(0) * r_blk) + off_ref[0, 0]
     t0 = t0_ref[0, 0]
@@ -245,22 +362,28 @@ def _fused_kernel(lat_ref, beta_ref, kw_ref, t0_ref, off_ref, out_ref, de_ref,
     def group(k, rows):
         rep = prng.iota_u32((g, 1, 1), 0) + (first + _u32(k * g))
         out_ref[rows], de_ref[rows], nacc_ref[rows] = _fused_sweeps(
-            kind, lat_ref[rows], beta_ref[rows], rep, sk, t0, n_sweeps, par
+            kind, lat_ref[rows], beta_ref[rows], rep, sk, t0, n_sweeps,
+            geometry,
         )
 
     _for_each_group(r_blk, g, group)
 
 
 def fused_call(kind: Kind, lattice, key_words, t0, betas, *, n_sweeps: int,
-               replica_offset, r_blk: int, interpret: bool):
+               replica_offset, r_blk: int, interpret: bool,
+               name: str | None = None):
     """``n_sweeps`` counter-PRNG sweeps of an (R, H, W) int8 lattice.
 
     ``key_words`` is the (2,) uint32 run key (`prng.key_words`), ``t0`` the
     (1,) uint32 sweep counter at entry and ``replica_offset`` the (1,)
     uint32 global index of local slot 0.  R must be a multiple of ``r_blk``.
+    A ``colour_split`` kind gets the lattice split by colour and merges it
+    on return.  ``name`` names the kernel's operation in a profile.
     Returns ``(lattice', delta_e (R,), n_accepted (R,))`` summed over the
     interval.
     """
+    if kind.colour_split:
+        lattice = split_rows(lattice)
     r, lat_shape = lattice.shape[0], lattice.shape[1:]
     assert r % r_blk == 0, (r, r_blk)
     out, de, nacc = pl.pallas_call(
@@ -274,7 +397,10 @@ def fused_call(kind: Kind, lattice, key_words, t0, betas, *, n_sweeps: int,
         out_shape=_row_outs(r, lat_shape),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=name,
     )(lattice, _col(betas), _row(key_words), _row(t0), _row(replica_offset))
+    if kind.colour_split:
+        out = merge_rows(out)
     return out, de.reshape(r), nacc.reshape(r)
 
 
@@ -298,7 +424,7 @@ def _round_kernel(lat_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref,
     """
     r = lat_ref.shape[0]
     g = r if kind.whole_block else 1
-    par = parity(*lat_ref.shape[-2:])
+    geometry = _geometry(kind, lat_ref.shape)
     kw = (kw_ref[0, 0], kw_ref[0, 1])
     sk = prng.stream_key(kw)
     t0, ph0 = t0_ref[0, 0], ph0_ref[0, 0]
@@ -316,7 +442,7 @@ def _round_kernel(lat_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref,
             beta = _kx.gather_row(betas_row, rung_out_ref[rows])
             rep = prng.iota_u32((g, 1, 1), 0) + _u32(gi * g)
             out_ref[rows], de, na = _fused_sweeps(
-                kind, src[rows], beta, rep, sk, t_round, n_sweeps, par
+                kind, src[rows], beta, rep, sk, t_round, n_sweeps, geometry
             )
             # as engine.driver does: the interval's ΔE is summed over its
             # sweeps first, then added onto the running per-slot energy
@@ -336,16 +462,20 @@ def _round_kernel(lat_ref, beta_ref, kw_ref, t0_ref, ph0_ref, rung_ref,
 
 def round_call(kind: Kind, lattice, key_words, t0, phase0, rung, energy,
                betas, *, n_sweeps: int, n_rounds: int, criterion: str,
-               pairing: str, interpret: bool):
+               pairing: str, interpret: bool, name: str | None = None):
     """``n_rounds`` × (``n_sweeps`` sweeps + exchange) in one launch.
 
     The exchange couples every replica, so the whole ladder is one grid
     step.  ``phase0`` is the (1,) int32 swap phase at entry, ``rung`` the
     (R,) slot→rung map, ``energy`` the (R,) per-slot energies and ``betas``
-    the (R,) rung-ordered ladder.  Returns ``(lattice', rung', energy',
-    n_accepted, accept, prob, attempt)`` with (R,) rows and (n_rounds, R)
-    diagnostics (accept/attempt as int32 0/1).
+    the (R,) rung-ordered ladder.  The lattice is split and merged for a
+    ``colour_split`` kind, as in `fused_call`, and ``name`` names the
+    kernel's operation.  Returns ``(lattice', rung', energy', n_accepted,
+    accept, prob, attempt)`` with (R,) rows and (n_rounds, R) diagnostics
+    (accept/attempt as int32 0/1).
     """
+    if kind.colour_split:
+        lattice = split_rows(lattice)
     r = lattice.shape[0]
     full = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
     col, diag = full((r, 1, 1)), full((n_rounds, r, 1, 1))
@@ -370,10 +500,11 @@ def round_call(kind: Kind, lattice, key_words, t0, phase0, rung, energy,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=name,
     )(lattice, _col(betas), _row(key_words), _row(t0), _row(phase0),
       _col(rung), _col(energy))
     return (
-        outs[0],
+        merge_rows(outs[0]) if kind.colour_split else outs[0],
         *(x.reshape(r) for x in outs[1:4]),
         *(x.reshape(n_rounds, r) for x in outs[4:]),
     )

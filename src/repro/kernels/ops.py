@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels import exchange as _kx
 from repro.kernels import ising_sweep as _ising
+from repro.kernels import lattice as _lattice
 from repro.kernels import potts_sweep as _potts
 from repro.kernels import prng as _prng
 from repro.kernels import ref as _ref
@@ -155,7 +156,11 @@ def ising_sweep_fused(
     ``pack_bits`` selects bit-plane multispin storage inside the kernel
     (`ising_sweep.vmem_working_set_bytes_packed`); the trajectory is
     bitwise-identical, so the reference path is packing-oblivious.
+    Without it the kernel sweeps the lattice split by colour
+    (`repro.kernels.lattice`), with the same result.  An odd lattice side
+    is refused (ValueError): periodic wrap leaves it no checkerboard.
     """
+    _lattice.require_even(spins.shape)
     words, t0 = _fused_prelude(key, t)
     off = jnp.asarray(replica_offset).astype(jnp.uint32).reshape(-1)[:1]
     r, length = spins.shape[0], spins.shape[-1]
@@ -287,10 +292,13 @@ def ising_round_fused(
     stream on ``phase`` makes the trajectory invariant to ``n_rounds``
     launch grouping: K rounds in one launch ≡ K single-round launches.
 
+    An odd lattice side is refused (ValueError), as in `ising_sweep_fused`.
+
     Returns ``(spins', rung', energy', n_accepted, accept, prob, attempt)``;
     diagnostics are (n_rounds, R) in `core.swap.accept_pairs` conventions
     (accept/attempt bool).
     """
+    _lattice.require_even(spins.shape)
     words, t0, ph0, rung, energy = _round_prelude(key, t, phase, rung, energy)
     r = spins.shape[0]
     if not use_pallas:

@@ -50,6 +50,8 @@ __all__ = [
     "sweep_key",
     "iota_u32",
     "to_uniform",
+    "site_index",
+    "site_uniforms",
     "plane_uniforms",
     "ising_sweep_uniforms",
     "potts_sweep_uniforms",
@@ -150,18 +152,32 @@ def to_uniform(bits: jnp.ndarray) -> jnp.ndarray:
     return top.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
 
 
-def plane_uniforms(w0, w1, plane: int, h: int, w: int) -> jnp.ndarray:
-    """(R, h, w) f32 uniforms in [0,1) for one random lattice ("plane").
+def site_index(h: int, w: int) -> jnp.ndarray:
+    """(h, w) uint32 linear site counter ``i*w + j`` of a whole lattice."""
+    return iota_u32((h, w), 0) * jnp.uint32(w) + iota_u32((h, w), 1)
 
-    ``w0``/``w1`` are per-replica sweep-key words shaped (R, 1, 1), which
-    broadcast against the lattice without a reshape inside a kernel body;
-    the site counter is the linear index ``i*w + j`` so the stream is
-    layout-independent (padding W for TPU lanes would not change values at
-    real sites).
+
+def site_uniforms(w0, w1, plane: int, site: jnp.ndarray) -> jnp.ndarray:
+    """f32 uniforms in [0,1) of one random lattice ("plane") at ``site``.
+
+    ``site`` holds the uint32 linear index ``i*W + j`` of each element's
+    lattice site, in whatever layout the caller keeps the lattice: a site
+    draws the same bits wherever it is stored.  ``w0``/``w1`` are
+    per-replica sweep-key words shaped (R, 1, 1), which broadcast against
+    the counters without a reshape inside a kernel body.
     """
-    site = iota_u32((h, w), 0) * jnp.uint32(w) + iota_u32((h, w), 1)
     b0, _ = threefry2x32(w0, w1, jnp.uint32(plane), site)
     return to_uniform(b0)
+
+
+def plane_uniforms(w0, w1, plane: int, h: int, w: int) -> jnp.ndarray:
+    """(R, h, w) f32 uniforms in [0,1) for one whole random lattice.
+
+    The counter is the linear index ``i*w + j`` (`site_index`), so the
+    stream is layout-independent: padding W for TPU lanes, or storing the
+    lattice split by colour, does not change the value at a real site.
+    """
+    return site_uniforms(w0, w1, plane, site_index(h, w))
 
 
 # -- pure-JAX per-sweep stream (the oracle's view of the kernel stream) --------

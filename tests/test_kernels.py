@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ising_sweep as isk
-from repro.kernels import ops, potts_sweep as psk, prng, ref
+from repro.kernels import lattice, ops, potts_sweep as psk, prng, ref
 
 
 # ΔE totals are f32 sums over the lattice; spins and counts are exact.
@@ -140,6 +140,7 @@ def test_prng_stream_distinct_across_counter_axes():
     (1, 4, 1), (8, 10, 4), (5, 12, 2),  # pad path
     (3, 6, 8),   # pad > R (regression: tiled padding)
     (4, 30, 4),  # odd (non-128-aligned) lattice like the paper's 300
+    (2, 14, 2),  # L ≡ 2 (mod 4): odd colour half-plane height L/2 = 7
 ])
 def test_ising_fused_bit_equals_persweep_oracle_stream(r, l, r_blk, n_sweeps):
     """The fused kernel over S sweeps must be BIT-equal in spins and
@@ -240,6 +241,89 @@ def test_fused_interval_equals_split_intervals():
     np.testing.assert_allclose(
         np.asarray(whole[1]), np.asarray(de1 + de2), rtol=1e-6, atol=1e-3
     )
+
+
+# ---------- colour-split layout of the fused and round kernels ------------------
+SPLIT_SIDES = [4, 6, 10, 30]  # L ≡ 0 and L ≡ 2 (mod 4): L/2 even and odd
+
+
+def _rand_lattice(l, r=3, seed=0):
+    x = np.random.RandomState(seed + l).choice([-1, 1], size=(r, l, l))
+    return jnp.asarray(x.astype(np.float32))
+
+
+def _colours(s):
+    """The colour half-planes X_0, X_1 of an (R, L, L) lattice, stacked."""
+    planes = lattice.split_rows(s)
+    return jnp.stack(lattice.swap_odd_columns(planes[:, 0], planes[:, 1]), 1)
+
+
+@pytest.mark.parametrize("l", SPLIT_SIDES)
+def test_colour_split_merge_round_trip(l):
+    s = _rand_lattice(l).astype(jnp.int8)
+    planes = lattice.split_rows(s)
+    assert planes.shape == (3, 2, l // 2, l)
+    np.testing.assert_array_equal(np.asarray(planes[:, 1, 0]), s[:, 1])
+    np.testing.assert_array_equal(np.asarray(lattice.merge_rows(planes)), s)
+    # leading batch axes (a vmapped chain ensemble) split the same way
+    batched = jnp.stack([s, -s])
+    np.testing.assert_array_equal(
+        np.asarray(lattice.split_rows(batched)[1]), -np.asarray(planes)
+    )
+    # the column swap into colour half-planes is its own inverse
+    x0, x1 = lattice.swap_odd_columns(planes[:, 0], planes[:, 1])
+    back = jnp.stack(lattice.swap_odd_columns(x0, x1), 1)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(planes))
+
+
+@pytest.mark.parametrize("l", SPLIT_SIDES)
+def test_colour_split_planes_hold_one_colour_and_its_site_counters(l):
+    """Every element of half-plane c is a site of colour c, and the counter
+    it draws at is that site's whole-plane linear index."""
+    par = _colours(lattice.parity(l, l)[None])[0]
+    site = _colours(prng.site_index(l, l)[None])[0]
+    colours, sites = lattice.colour_split_geometry(l // 2, l)
+    np.testing.assert_array_equal(
+        np.asarray(colours), np.arange(l)[None].repeat(l // 2, 0) % 2
+    )
+    for c in (0, 1):
+        assert (np.asarray(par[c]) == c).all()
+        np.testing.assert_array_equal(np.asarray(sites(c)), np.asarray(site[c]))
+
+
+@pytest.mark.parametrize("l", SPLIT_SIDES)
+def test_colour_split_neighbour_sum_equals_full_plane(l):
+    """The split layout's 4-neighbour sum of colour c's sites, from the
+    other colour's half-plane, equals the whole plane's roll sum there."""
+    s = _rand_lattice(l)
+    full = sum(lattice.roll1(s, d, ax) for d in (1, -1) for ax in (1, 2))
+    planes = _colours(s)
+    want = _colours(full)
+    colours, _ = lattice.colour_split_geometry(l // 2, l)
+    for c in (0, 1):
+        got = lattice.split_neighbours(planes[:, 1 - c], colours, c)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want[:, c]))
+
+
+@pytest.mark.parametrize("fn", ["sweep", "round"])
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_ising_fused_refuses_odd_lattice(fn, use_pallas):
+    """Periodic wrap leaves an odd side no checkerboard: the fused and
+    round entry points refuse it instead of sweeping a wrong one."""
+    key = jax.random.key(0)
+    spins, _, betas = _rand_ising(key, 2, 5)
+    with pytest.raises(ValueError, match="even lattice sides"):
+        if fn == "sweep":
+            ops.ising_sweep_fused(
+                spins, key, jnp.int32(0), betas, n_sweeps=1,
+                use_pallas=use_pallas,
+            )
+        else:
+            ops.ising_round_fused(
+                spins, key, jnp.int32(0), jnp.int32(0),
+                jnp.arange(2, dtype=jnp.int32), jnp.zeros((2,)), betas,
+                n_sweeps=1, use_pallas=use_pallas,
+            )
 
 
 # ---------- per-sweep padding regression: pad > R (e.g. R=3 at r_blk=8) ---------

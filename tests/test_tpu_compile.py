@@ -18,6 +18,7 @@ from repro.kernels import ising_sweep as isk
 from repro.kernels import potts_sweep as psk
 
 L = 300  # the paper's lattice
+L_2_MOD_4 = 298  # odd colour half-plane height: L/2 = 149
 Q = 3
 VMEM_MODEL_BUDGET = 16 * 2**20  # the models' v5e budget (tests/test_kernels.py)
 
@@ -69,22 +70,25 @@ def spec(one_chip):
     )
 
 
-def _kernel_cases(spec):
-    """(function, argument shapes) of each main-path kernel at L=300."""
+def _kernel_cases(spec, length=L):
+    """(function, argument shapes) of each main-path kernel at side ``length``."""
     i8, u32, i32, f32 = jnp.int8, jnp.uint32, jnp.int32, jnp.float32
     fused_args = lambda r: [
-        spec((r, L, L), i8), spec((r,), f32), spec((2,), u32),
+        spec((r, length, length), i8), spec((r,), f32), spec((2,), u32),
         spec((1,), u32), spec((1,), u32),
     ]
     round_args = lambda r: [
-        spec((r, L, L), i8), spec((2,), u32), spec((1,), u32),
+        spec((r, length, length), i8), spec((2,), u32), spec((1,), u32),
         spec((1,), i32), spec((r,), i32), spec((r,), f32), spec((r,), f32),
     ]
     return {
         "ising_sweep": (
             lambda s, u, b: isk.ising_sweep_pallas(
                 s, u, b, r_blk=8, interpret=False),
-            [spec((16, L, L), i8), spec((16, 2, L, L), f32), spec((16,), f32)],
+            [
+                spec((16, length, length), i8),
+                spec((16, 2, length, length), f32), spec((16,), f32),
+            ],
         ),
         "ising_fused": (
             lambda s, b, kw, t0, off: isk.ising_sweep_fused_pallas(
@@ -100,7 +104,10 @@ def _kernel_cases(spec):
         "potts_sweep": (
             lambda s, u, b: psk.potts_sweep_pallas(
                 s, u, b, q=Q, r_blk=4, interpret=False),
-            [spec((8, L, L), i8), spec((8, 2, 2, L, L), f32), spec((8,), f32)],
+            [
+                spec((8, length, length), i8),
+                spec((8, 2, 2, length, length), f32), spec((8,), f32),
+            ],
         ),
         "potts_fused": (
             lambda s, b, kw, t0, off: psk.potts_sweep_fused_pallas(
@@ -131,6 +138,20 @@ def test_kernel_compiles_for_v5e(spec, name):
     fn, args = _kernel_cases(spec)[name]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("length", [L, L_2_MOD_4])
+@pytest.mark.parametrize("name,op", [
+    ("ising_fused", "ising_sweep_fused_split"),
+    ("ising_round", "ising_round_fused_split"),
+])
+def test_colour_split_kernel_compiles_for_v5e(spec, name, op, length):
+    """The colour-split Ising launches compile at L ≡ 0 and L ≡ 2 (mod 4),
+    and a profile names their operation after the layout."""
+    fn, args = _kernel_cases(spec, length)[name]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{op}" in text
 
 
 def test_ensemble_fused_kernel_compiles_for_v5e(spec):
