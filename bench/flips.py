@@ -1,12 +1,14 @@
 """Spin-flip attempts: the work a sampling cell completes.
 
 One sweep proposes a flip at every site of every replica, so a window of
-``sweeps`` sweeps over ``chains`` chains of ``replicas`` lattices of side
-``length`` makes ``sweeps * chains * replicas * length**2`` attempts,
-whichever chips the replicas are spread over.
+``sweeps`` sweeps over ``chains`` chains of ``replicas`` lattices of shape
+``shape`` (of any rank) makes ``sweeps * chains * replicas * prod(shape)``
+attempts, whichever chips the replicas are spread over.
 """
 from __future__ import annotations
 
+import math
 
-def flip_attempts(sweeps: int, replicas: int, length: int, chains: int = 1) -> int:
-    return int(sweeps) * int(chains) * int(replicas) * int(length) ** 2
+
+def flip_attempts(sweeps: int, replicas: int, shape, chains: int = 1) -> int:
+    return int(sweeps) * int(chains) * int(replicas) * math.prod(int(n) for n in shape)

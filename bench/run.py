@@ -8,7 +8,8 @@ its configuration in ``bench/configs/<config>.json``, its traffic mix in
 ``bench/traffic/<kind>.py``, the configuration's plain reference in
 ``bench/reference/<reference>.py`` and each per-layer metric in
 ``bench/metrics/<metric>.py``.  ``BENCHMARK.json`` says which metrics a cell
-reports.
+reports.  Every kind that drives `repro.api.Session` times it with the one
+window of ``bench/traffic/timed_window.py``.
 
 The run needs as many TPU chips as the cell asks for and exits non-zero,
 with no result, without them.  It keeps JAX's compilation cache in
@@ -22,8 +23,10 @@ One further option is for measuring the benchmark itself, never for its
 runs: ``--control 1`` puts the reference, computed in bfloat16, in the
 program's place (the comparison has to fail it).
 
-A run is correct when every check is within its cell's limit and the
-engine did not degrade (``failed``).
+A kind declares the checks it reports in ``CHECKS``, and its cells give
+each a limit; a record that reports other checks is refused.  A run is
+correct when every check is within its cell's limit and the engine did not
+degrade (``failed``).
 """
 from __future__ import annotations
 
@@ -195,6 +198,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     tracer = Tracer(TRACE_DIR / name if trace else None)
     ctx = Context(cell, int(seed), float(seconds), tracer, reference, control=control)
     record = kind.run(ctx)
+    if set(record["checks"]) != set(kind.CHECKS):
+        raise ValueError(f"kind {cell.traffic['kind']!r} reported the checks "
+                         f"{sorted(record['checks'])}, but declares {sorted(kind.CHECKS)}")
     device["memory_peak_bytes"] = record["memory_peak_bytes"]
 
     metrics = {}

@@ -1,22 +1,15 @@
-"""Traffic kind ``session_window``: one long run through `repro.api.Session`.
+"""Traffic kind ``session_window``: one long run of the Ising configuration
+through `repro.api.Session`, timed by the shared window of
+``bench/traffic/timed_window.py``.
 
-The schedule is a warm-up phase of one chunk and a window phase longer than
-any run, which a callback ends once ``--seconds`` have passed.  The window
-opens when the warm-up chunk has finished on the device and closes when the
-chunk that was running at the deadline has finished: it is a whole number
-of chunks, ended by ``block_until_ready``.  The host is held at most one
-chunk ahead of the device, so the device always has the next chunk queued.
-
-At each chunk boundary the callback copies the lattice, energies, rungs and
-counters (the engine donates its state to the next chunk), so that the last
-chunk of the window can be replayed by the plain reference from the state
-it started from.  The initial state is copied too, before the warm-up.
 The mix file gives ``system``, the kernel flags the window's `Session`
 passes to the system, and may give ``mesh``, the ``MeshSpec`` that splits
-the replicas over the cell's chips.  Kernels are strict: a kernel that
-cannot run on the chip fails the run instead of degrading.
+the replicas over the cell's chips.  The window copies the lattice,
+energies, rungs and counters at each chunk boundary, and the initial state
+before the warm-up.
 
-After the window, with the program's state freed, the reference checks:
+After the window, with the program's state freed, the reference checks
+(`CHECKS`, each with a limit in the cell's workload file):
 
 * ``replicas_off`` -- replicas whose lattice differs from the reference's,
   at the start (the lattice drawn from the seed) or after the last chunk;
@@ -28,22 +21,22 @@ After the window, with the program's state freed, the reference checks:
 from __future__ import annotations
 
 import math
-import time
 
 import numpy as np
 
 from bench import flips
-from bench.device import free_device_memory, memory_peak_bytes
+from bench.traffic import timed_window
 
-WINDOW_SWEEPS = 100 * 10**9  # longer than any run; the callback ends it
+CHECKS = ("replicas_off", "rungs_off", "energy_off")
+# the PTState fields the reference's replay starts from
+COPY_KEYS = ("states", "energy", "rung", "t", "phase")
 # Replicas the reference sweeps at a time, so that at the paper's size it
 # fits beside the copies the window kept.
 REFERENCE_BLOCK = 256
 
 
-def _spec(ctx, warm_sweeps: int):
-    from repro.api import (EngineSpec, LadderSpec, PhaseSpec, RunSpec,
-                           ScheduleSpec, SystemSpec)
+def _spec(ctx, chunk_sweeps: int):
+    from repro.api import EngineSpec, LadderSpec, RunSpec, SystemSpec
     from repro.core.distributed import MeshSpec
 
     dep = ctx.cell.config
@@ -58,92 +51,26 @@ def _spec(ctx, warm_sweeps: int):
                           chunk_intervals=dep["chunk_intervals"],
                           criterion=dep["criterion"],
                           mesh=MeshSpec(**mesh) if mesh else None),
-        schedule=ScheduleSpec(phases=(PhaseSpec("warm", warm_sweeps),
-                                      PhaseSpec("window", WINDOW_SWEEPS))),
+        schedule=timed_window.schedule(chunk_sweeps),
         observables=(),
         seed=ctx.seed,
     )
 
 
-def _copy(pt):
-    """Device copies of the leaves a replay needs (the engine donates them)."""
-    import jax.numpy as jnp
-
-    return {k: jnp.copy(getattr(pt, k))
-            for k in ("states", "energy", "rung", "t", "phase")}
-
-
-def _host(tree):
-    return {k: np.asarray(v) for k, v in tree.items()}
-
-
 def run(ctx) -> dict:
-    import jax
-
-    from repro.api import Session
-    from repro.api.session import Callback
-
     dep = ctx.cell.config
     chunk_sweeps = dep["swap_interval"] * dep["chunk_intervals"]
-    tracer = ctx.tracer
-
-    class Window(Callback):
-        start = before = pending = None
-        t0 = sweep0 = None
-
-        def on_phase_start(self, session, phase):
-            if phase.name == "warm":
-                self.start = _copy(session.state.pt)
-                return
-            jax.block_until_ready(session.state)
-            self.sweep0 = int(np.asarray(session.state.pt.t).reshape(-1)[0])
-            self.pending = _copy(session.state.pt)
-            jax.block_until_ready(self.pending)
-            tracer.start()
-            self.t0 = time.perf_counter()
-
-        def on_chunk(self, session, info):
-            if session.current_phase.name != "window":
-                return False
-            with tracer.span("bench.chunk_boundary"):
-                self.before = self.pending  # the state this chunk started from
-                jax.block_until_ready(self.before["t"])  # the previous chunk is done
-                if time.perf_counter() - self.t0 >= ctx.seconds:
-                    return True
-                self.pending = _copy(info.state.pt)
-                return False
-
-    window = Window()
-    session = Session(_spec(ctx, chunk_sweeps), callbacks=[window], strict_kernels=True)
-    result = session.run()
-    final = result.state
-    with tracer.span("bench.window_close"):
-        jax.block_until_ready(final)
-    t_end = time.perf_counter()
-    tracer.stop()
-    window_s = t_end - window.t0
-    setup_s = window.t0 - ctx.process_t0
-
-    devices = sorted({d for leaf in jax.tree_util.tree_leaves(final.pt.states)
-                      for d in leaf.devices()}, key=lambda d: d.id)
-    peak = memory_peak_bytes(devices)
-    start, before = _host(window.start), _host(window.before)
-    after = _host({k: getattr(final.pt, k) for k in ("states", "energy", "rung", "t", "phase")})
-    degraded = bool(session.engine._degraded)
-    sweeps = int(after["t"].reshape(-1)[0]) - window.sweep0
-    del session, result, final, window
-    free_device_memory()
-
-    checks = check(ctx, start, before, after)
-    n_flips = flips.flip_attempts(sweeps, dep["n_replicas"], dep["length"])
+    w = timed_window.run_window(ctx, _spec(ctx, chunk_sweeps), COPY_KEYS)
+    checks = check(ctx, w.start, w.before, w.after)
+    n_flips = flips.flip_attempts(w.sweeps, dep["n_replicas"], (dep["length"],) * 2)
     return {
-        "setup_s": setup_s,
-        "end_to_end": {"flips_per_s": n_flips / window_s},
+        "setup_s": w.setup_s,
+        "end_to_end": {"flips_per_s": n_flips / w.window_s},
         "flips": n_flips,
         "chips": ctx.cell.chips,
-        "attempted": sweeps // chunk_sweeps,
-        "failed": int(degraded),
-        "memory_peak_bytes": peak,
+        "attempted": w.sweeps // chunk_sweeps,
+        "failed": int(w.degraded),
+        "memory_peak_bytes": w.memory_peak_bytes,
         "checks": checks,
     }
 
