@@ -27,6 +27,7 @@ state leaf             C == 1                     C > 1 (ensemble)
 ``pt.states`` leaves   P('replicas', ...)         P('chains', 'replicas', ...)
 ``pt.energy/rung``     P('replicas')              P('chains', 'replicas')
 ``pt.key/phase/t``     P() (replicated)           P('chains')
+``pt.quenched``        P() (replicated)           P('chains')
 ``stats`` leaves       P(None, ...) (replicated)  P('chains', None, ...)
 ``betas``              P(None) (replicated)       P(None) (replicated)
 =====================  =========================  =========================
@@ -140,7 +141,7 @@ def pt_partition_specs(state: PTState, n_chains: int) -> PTState:
 
     Replica-population leaves shard their slot axis over ``replicas`` (and
     the leading chain axis over ``chains`` with an ensemble); per-chain
-    scalars (key/phase/t) replicate along ``replicas``.
+    scalars (key/phase/t) and quenched data replicate along ``replicas``.
     """
     lead = (CHAIN_AXIS,) if n_chains > 1 else ()
     nl = len(lead)
@@ -158,6 +159,7 @@ def pt_partition_specs(state: PTState, n_chains: int) -> PTState:
         key=chain_only(state.key),
         phase=chain_only(state.phase),
         t=chain_only(state.t),
+        quenched=jax.tree_util.tree_map(chain_only, state.quenched),
     )
 
 
@@ -204,13 +206,11 @@ def shard_state(state: PTState, shard: NamedSharding) -> PTState:
             return jax.lax.with_sharding_constraint(x, shard)
         return x
 
-    return PTState(
+    return dataclasses.replace(
+        state,
         states=jax.tree_util.tree_map(constrain, state.states),
         energy=constrain(state.energy),
         rung=constrain(state.rung),
-        key=state.key,
-        phase=state.phase,
-        t=state.t,
     )
 
 

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.systems import System, batched_energy, batched_init
+from repro.core.systems import System, batched_energy, batched_init, has_quenched
 
 __all__ = ["PTConfig", "PTState", "init", "init_replicas", "run", "make_run"]
 
@@ -55,6 +55,9 @@ class PTState:
     key: jax.Array  # PRNG key
     phase: jax.Array  # i32 swap-phase alternator (paper Fig. 2)
     t: jax.Array  # i32 sweep counter
+    # the chain's quenched data (`repro.core.systems.has_quenched`), with no
+    # replica axis; None for systems without it
+    quenched: Any = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,19 +109,24 @@ class PTConfig:
 
 
 def init_replicas(
-    system: System, n_replicas: int, key: jax.Array, *, shard=None
+    system: System, n_replicas: int, key: jax.Array, *, shard=None,
+    quenched=None,
 ) -> PTState:
     """Build the initial PT state (paper's "initialization phase").
 
     The single source of truth for state construction — the engine
     (`repro.engine.driver`) and the `init` wrapper below both use it, which
     keeps their PRNG streams (and hence trajectories) identical.
+    ``quenched`` is the chain's quenched data; a system that holds such
+    data gets chain 0's where none is given.
     """
+    if quenched is None and has_quenched(system):
+        quenched = system.init_quenched(0)
     k_init, k_run = jax.random.split(key)
     states = batched_init(system, k_init, n_replicas)
     if shard is not None:
         states = jax.lax.with_sharding_constraint(states, shard)
-    energy = batched_energy(system, states)
+    energy = batched_energy(system, states, quenched)
     return PTState(
         states=states,
         energy=energy.astype(jnp.float32),
@@ -126,6 +134,7 @@ def init_replicas(
         key=k_run,
         phase=jnp.int32(0),
         t=jnp.int32(0),
+        quenched=quenched,
     )
 
 

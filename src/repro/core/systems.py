@@ -58,6 +58,19 @@ class System(Protocol):
         ...
 
 
+# A system may also hold *per-chain quenched data* (a spin glass's bonds):
+# it then defines ``init_quenched(chain) -> pytree``, built from the chain
+# index, and takes that pytree as a last argument of ``energy(state, q)``
+# and ``mcmc_step(key, state, beta, q)``.  The engine builds it once per
+# chain at init and passes it un-batched over replicas.
+
+
+def has_quenched(system) -> bool:
+    """Whether ``system`` (an instance or a class) holds per-chain quenched
+    data."""
+    return callable(getattr(system, "init_quenched", None))
+
+
 def batched_init(system: System, key: jax.Array, n_replicas: int) -> State:
     """Initialize ``n_replicas`` independent replica states.
 
@@ -72,7 +85,9 @@ def batched_init(system: System, key: jax.Array, n_replicas: int) -> State:
     return jax.vmap(system.init_state)(keys)
 
 
-def batched_energy(system: System, states: State) -> jax.Array:
+def batched_energy(system: System, states: State, quenched=None) -> jax.Array:
+    if quenched is not None:
+        return jax.vmap(system.energy, in_axes=(0, None))(states, quenched)
     fast = getattr(system, "batched_energy", None)
     if fast is not None:
         return fast(states)
@@ -221,7 +236,7 @@ def _register_zoo():
     from repro.core.hp import HPChain, radius_of_gyration_sq
     from repro.core.ising import IsingSystem, magnetization
     from repro.core.potts import PottsSystem, potts_magnetization
-    from repro.core.spin_glass import EASpinGlass
+    from repro.core.spin_glass import EAGlass, EASpinGlass
 
     register_constructor(
         "ising",
@@ -255,6 +270,15 @@ def _register_zoo():
         observables={
             "absmag": lambda s: (
                 lambda x: jnp.abs(jnp.mean(x["spins"].astype(jnp.float32)))
+            ),
+        },
+    )
+    register_constructor(
+        "ea_glass",
+        EAGlass,
+        observables={
+            "absmag": lambda s: (
+                lambda x: jnp.abs(jnp.mean(x.astype(jnp.float32)))
             ),
         },
     )
@@ -293,6 +317,15 @@ def _register_zoo():
     register(RegisteredSystem(
         name="ea_spin_glass",
         params={"shape": (4, 4), "disorder_seed": 1, "accept_rule": "glauber"},
+        observable_names=("absmag",),
+        temps=(0.8, 1.2, 1.8, 2.7, 4.0),
+    ))
+    # copies == n_chains: every chain holds sample 0, the one the exact
+    # reference enumerates (repro.validate.exact.ea_glass_exact)
+    register(RegisteredSystem(
+        name="ea_glass",
+        params={"shape": (2, 2, 4), "disorder_seed": 1, "copies": 2,
+                "accept_rule": "glauber"},
         observable_names=("absmag",),
         temps=(0.8, 1.2, 1.8, 2.7, 4.0),
     ))

@@ -53,7 +53,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import distributed as dist_lib
 from repro.core.distributed import CHAIN_AXIS, MeshSpec, REPLICA_AXIS
 from repro.core.pt import PTState, init_replicas as pt_init_replicas
-from repro.core.systems import System
+from repro.core.systems import System, has_quenched
 from repro.engine import stats as stats_lib
 from repro.engine.adapt import AdaptConfig, AdaptState, maybe_adapt
 from repro.exchange import DEO, ExchangeStrategy, make_strategy
@@ -116,6 +116,22 @@ def _batched_step(system: System):
     if fn is not None:
         return fn
     return jax.vmap(system.mcmc_step)
+
+
+def _replica_step(system: System, keys, states, betas, quenched):
+    """One step of every replica; the chain's quenched data, where the
+    system holds some, goes to each replica's step un-batched."""
+    if quenched is None:
+        return _batched_step(system)(keys, states, betas)
+
+    def step(key, state, beta, q):
+        # vmap renames the first scope opened inside it ("vmap(pt.replica)"):
+        # this one takes that, so the step's own scopes (pt.rng, pt.field)
+        # keep their names in the ops' metadata and the profile
+        with jax.named_scope("pt.replica"):
+            return system.mcmc_step(key, state, beta, q)
+
+    return jax.vmap(step, in_axes=(0, 0, 0, None))(keys, states, betas, quenched)
 
 
 def _batched_interval(system: System):
@@ -185,7 +201,8 @@ def _sweep_once(system, spec: StepSpec, betas, st: PTState, shard=None) -> PTSta
             # the whole PRNG stream — measured 16x redundant HBM traffic)
             keys = jax.lax.with_sharding_constraint(keys, shard)
         betas_slot = betas[st.rung]
-        states, de, _ = _batched_step(system)(keys, st.states, betas_slot)
+        states, de, _ = _replica_step(system, keys, st.states, betas_slot,
+                                      st.quenched)
         return dataclasses.replace(
             st,
             states=states,
@@ -451,8 +468,8 @@ def make_sharded_interval_step(
                             jax.random.fold_in(s.key, 2 * s.t),
                             offset + jnp.arange(r_local, dtype=jnp.uint32),
                         )
-                    states, de, _ = _batched_step(system)(
-                        keys, s.states, betas[s.rung])
+                    states, de, _ = _replica_step(
+                        system, keys, s.states, betas[s.rung], s.quenched)
                     return dataclasses.replace(
                         s,
                         states=states,
@@ -880,16 +897,29 @@ class Engine:
         self.timeline = NULL if value is None else value.timeline
 
     # -- state construction ----------------------------------------------------
-    def _init_single(self, key: jax.Array) -> PTState:
+    def _init_single(self, key: jax.Array, quenched=None) -> PTState:
         # one chain = seed init verbatim (keeps pt-vs-engine bit-equality)
-        return pt_init_replicas(self.system, self.config.n_replicas, key)
+        return pt_init_replicas(self.system, self.config.n_replicas, key,
+                                quenched=quenched)
+
+    def _quenched(self, chains):
+        """The quenched data of the chains with indices ``chains`` (stacked
+        on a leading axis), or of chain 0 alone where ``chains`` is None;
+        None for a system that holds none."""
+        if not has_quenched(self.system):
+            return None
+        with self.timeline.span("repro.engine.quenched"):
+            if chains is None:
+                return self.system.init_quenched(0)
+            return jax.vmap(self.system.init_quenched)(chains)
 
     def init(self, key: jax.Array, temps) -> EngineState:
         """Fresh engine state on the given temperature ladder.
 
         With an ensemble, chain ``c`` is seeded from ``fold_in(key, c)`` —
         independent of ``n_chains``, so growing the ensemble never perturbs
-        existing chains.
+        existing chains.  A system's per-chain quenched data is built here,
+        once, from each chain's index (`PTState.quenched`).
         """
         temps = np.asarray(temps, np.float64)
         if temps.shape != (self.config.n_replicas,):
@@ -926,7 +956,13 @@ class Engine:
         self._temps = temps.copy()
         self._adapt_state = None
         c = self.config.n_chains
-        per_chain = [self._init_single(k) for k in keys]
+        quenched = self._quenched(jnp.arange(c, dtype=jnp.uint32))
+        per_chain = [
+            self._init_single(
+                k, None if quenched is None
+                else jax.tree_util.tree_map(lambda q: q[i], quenched))
+            for i, k in enumerate(keys)
+        ]
         if c == 1:
             pt_st = per_chain[0]
         else:
@@ -943,12 +979,11 @@ class Engine:
         """`init` minus placement/host bookkeeping (eval_shape-safe)."""
         c = self.config.n_chains
         if c == 1:
-            pt_st = self._init_single(key)
+            pt_st = self._init_single(key, self._quenched(None))
         else:
-            keys = jax.vmap(jax.random.fold_in, (None, 0))(
-                key, jnp.arange(c, dtype=jnp.uint32)
-            )
-            pt_st = jax.vmap(self._init_single)(keys)
+            chains = jnp.arange(c, dtype=jnp.uint32)
+            keys = jax.vmap(jax.random.fold_in, (None, 0))(key, chains)
+            pt_st = jax.vmap(self._init_single)(keys, self._quenched(chains))
         stats = stats_lib.init_stats(
             self.config.n_replicas, self._names, n_chains=0 if c == 1 else c
         )

@@ -14,8 +14,10 @@ existing ensemble axis and advanced by a single executable.  The pieces:
 * `check_servable` — the packing preconditions, rejected loudly at submit
   time: no adaptive phases (`repro.engine.adapt` pools swap counters over
   the whole ensemble and retunes the shared ladder — one tenant's feedback
-  would perturb every other tenant's trajectory) and no explicit device mesh
-  (the scheduler owns placement).
+  would perturb every other tenant's trajectory), no explicit device mesh
+  (the scheduler owns placement) and no system with per-chain quenched data
+  (a packed chain slot is not its job's chain, so its disorder sample
+  would be another's).
 * `PackedRun` — one live bucket: the packed `EngineState`, the job -> chain
   slot map, the schedule cursor, per-job observable streaming and failure
   isolation, and checkpoint save/restore for preemption.
@@ -39,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import systems as systems_lib
 from repro.engine import stats as stats_lib
 from repro.serve.job import Job, JobResult, JobUpdate
 
@@ -80,6 +83,14 @@ def check_servable(spec) -> None:
         raise ValueError(
             "spec.engine.mesh is set: the serve scheduler owns device "
             "placement; submit specs with mesh=None"
+        )
+    entry = systems_lib.CONSTRUCTORS.get(spec.system.name)
+    if entry is not None and systems_lib.has_quenched(entry.build):
+        raise ValueError(
+            f"system {spec.system.name!r} holds per-chain quenched data "
+            "(disorder samples drawn from the chain index); a bucket packs "
+            "several jobs' chains into one ensemble, and per-job disorder "
+            "is not supported there.  Run it as a solo Session."
         )
 
 

@@ -62,6 +62,7 @@ EXACT = {
     "gaussian": exact_lib.gaussian_exact,
     "potts": exact_lib.potts_exact,
     "ea_spin_glass": exact_lib.ea_exact,
+    "ea_glass": exact_lib.ea_glass_exact,
     "hp_protein": exact_lib.hp_exact,
 }
 

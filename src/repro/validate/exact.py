@@ -23,6 +23,7 @@ __all__ = [
     "ising_exact",
     "potts_exact",
     "ea_exact",
+    "ea_glass_exact",
     "gaussian_exact",
     "hp_exact",
     "enumerate_saws",
@@ -88,6 +89,21 @@ def ea_exact(system, temps) -> dict[str, np.ndarray]:
         jd[None] * s * np.roll(s, -1, axis=1)
     ).sum(axis=(1, 2))
     absm = np.abs(s.mean(axis=(1, 2)))
+    return boltzmann_means(e, {"absmag": absm}, temps)
+
+
+def ea_glass_exact(system, temps) -> dict[str, np.ndarray]:
+    """Exact ⟨E⟩ / ⟨|m|⟩ for `repro.core.spin_glass.EAGlass` on the bonds of
+    sample 0 — the zoo entry sets ``copies`` to its chain count, so every
+    chain of the run holds them."""
+    shape = system.shape
+    bonds = np.asarray(system.plain(system.init_quenched(0)), np.float64)
+    s = _spin_configs(int(np.prod(shape))).reshape(-1, *shape).astype(np.float64)
+    sites = tuple(range(1, len(shape) + 1))
+    e = np.zeros(s.shape[0])
+    for d in range(len(shape)):
+        e -= system.j * (bonds[d][None] * s * np.roll(s, -1, axis=d + 1)).sum(axis=sites)
+    absm = np.abs(s.mean(axis=sites))
     return boltzmann_means(e, {"absmag": absm}, temps)
 
 

@@ -1,8 +1,8 @@
 """Statistical conformance: the engine vs exact ground truth, per system.
 
 One parametrized case per `repro.core.systems.REGISTRY` entry — every system
-in the zoo (Ising, Gaussian, Potts, EA spin glass, HP protein) runs through
-the *production* path (chunked streaming engine, adaptive ladder ON,
+in the zoo (Ising, Gaussian, Potts, both EA spin glasses, HP protein) runs
+through the *production* path (chunked streaming engine, adaptive ladder ON,
 ``n_chains > 1`` ensemble axis ON) and its sampled ⟨E⟩ + order-parameter
 means must match exact enumeration / analytic values within 4x the
 batch-means MCSE at every rung of the final adapted ladder
@@ -27,13 +27,15 @@ CASES = [
 
 def test_registry_covers_expected_zoo():
     """The zoo the paper motivates: lattice benchmark (Ising), multimodal
-    toy (Gaussian), beyond-Ising lattice (Potts), disordered (EA), and the
-    protein-folding workload (HP) — each with an exact reference."""
+    toy (Gaussian), beyond-Ising lattice (Potts), disordered (EA in 2-D, and
+    EA over disorder samples in 2-D or 3-D), and the protein-folding
+    workload (HP) — each with an exact reference."""
     assert set(systems.REGISTRY) == {
         "ising",
         "gaussian",
         "potts",
         "ea_spin_glass",
+        "ea_glass",
         "hp_protein",
     }
     assert set(EXACT) == set(systems.REGISTRY)

@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e, at the paper's L=300.
+"""The Pallas kernels compile for a TPU v5e, at the paper's L=300, and the
+3-D glass's mega-step fits one v5e at the Janus size.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  Interpret-mode tests cannot see what Mosaic refuses (block
@@ -9,8 +10,12 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and test workers
 import every test file.
 """
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -161,3 +166,30 @@ def test_ensemble_fused_kernel_compiles_for_v5e(spec):
     batched = [spec((2, *a.shape), a.dtype) for a in args]
     compiled = jax.jit(jax.vmap(fn)).lower(*batched).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_glass_mega_step_fits_one_v5e(one_chip):
+    """The ``glass3d_janus`` deployment (bench/configs): 256 chains × 34
+    rungs of 32³ spins.  Lane-dense lattices and bonds held once per chain
+    keep the compiled chunk's arguments and temporaries far inside 16 GB."""
+    from repro.core.spin_glass import EAGlass
+    from repro.engine import Engine, EngineConfig
+
+    dep = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                      / "glass3d_janus.json").read_text())
+    chains = dep["n_samples"] * dep["copies"]
+    eng = Engine(EAGlass(shape=tuple(dep["shape"]), copies=dep["copies"]),
+                 EngineConfig(n_replicas=dep["n_replicas"], swap_interval=dep["swap_interval"],
+                              criterion=dep["criterion"], chunk_intervals=1, n_chains=chains))
+    temps = np.linspace(dep["ladder"]["t_min"], dep["ladder"]["t_max"], dep["n_replicas"])
+    shapes = jax.eval_shape(lambda k: eng._fresh_state(k, temps), jax.random.key(0))
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    assert shapes.pt.quenched.shape == (chains, 3, 32, 32 * 32)
+    assert shapes.pt.quenched.dtype == jnp.int8
+    mem = jax.jit(eng._make_mega(1, shapes), donate_argnums=(0, 1)).lower(
+        on_chip(shapes.pt), on_chip(shapes.stats), on_chip(shapes.betas)
+    ).compile().memory_analysis()
+    sites = chains * dep["n_replicas"] * 32**3
+    assert mem.argument_size_in_bytes < 1.2 * sites  # 1 B of spins a site, no padding
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 * 10**9
